@@ -1,4 +1,5 @@
-"""Microbenchmarks for the Pallas kernels (interpret mode on CPU)."""
+"""Microbenchmarks for the Pallas kernels (compiled on TPU, interpreted
+elsewhere)."""
 from __future__ import annotations
 
 

@@ -467,9 +467,8 @@ def main() -> None:
     print(f"# benchmarks.run: targets={','.join(names)} full={args.full} "
           f"base_seed={args.seed}", flush=True)
     from repro.sim import configure_xla_cache
-    xla_cache = configure_xla_cache()   # REPRO_XLA_CACHE: warm runs skip
-    if xla_cache:                       # recompiling the bucketed kernels
-        print(f"# xla compilation cache: {xla_cache}", flush=True)
+    # warm runs skip recompiling the bucketed kernels
+    print(f"# xla compilation cache: {configure_xla_cache()}", flush=True)
     if args.trace:
         obs.enable()
         os.makedirs(args.trace, exist_ok=True)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -61,8 +63,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          causal: bool = True, bq: int = 256, bk: int = 256,
-                         interpret: bool = True) -> jnp.ndarray:
-    """q: (BH, Sq, D); k, v: (BH, Sk, D) — same head count (pre-broadcast GQA)."""
+                         interpret: bool | None = None) -> jnp.ndarray:
+    """q: (BH, Sq, D); k, v: (BH, Sk, D) — same head count (pre-broadcast GQA).
+
+    ``interpret`` follows :func:`repro.kernels.resolve_interpret`: compiled
+    on a TPU backend, interpreted elsewhere."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq = min(bq, sq)
@@ -89,5 +94,5 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

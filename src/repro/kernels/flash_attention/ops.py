@@ -9,9 +9,9 @@ import jax.numpy as jnp
 from .flash_attention import flash_attention_bhsd
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal",))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, interpret: bool = True) -> jnp.ndarray:
+                    causal: bool = True) -> jnp.ndarray:
     """q: (B, S, H, Dh); k, v: (B, S, Hkv, Dh) with H = G·Hkv (GQA)."""
     b, s, h, dh = q.shape
     hkv = k.shape[2]
@@ -22,5 +22,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
     kf = kb.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
     vf = vb.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
-    out = flash_attention_bhsd(qf, kf, vf, causal=causal, interpret=interpret)
+    out = flash_attention_bhsd(qf, kf, vf, causal=causal)
     return out.reshape(b, h, s, dh).transpose(0, 2, 1, 3)

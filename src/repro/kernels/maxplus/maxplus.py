@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -41,8 +43,11 @@ def _maxplus_kernel(a_ref, b_ref, o_ref, *, bk: int, nk: int):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def maxplus_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
                    bn: int = 128, bk: int = 128,
-                   interpret: bool = True) -> jnp.ndarray:
-    """C[i, j] = max_k (A[i, k] + B[k, j]).  a: (m, k); b: (k, n) float32."""
+                   interpret: bool | None = None) -> jnp.ndarray:
+    """C[i, j] = max_k (A[i, k] + B[k, j]).  a: (m, k); b: (k, n) float32.
+
+    ``interpret`` follows :func:`repro.kernels.resolve_interpret`: compiled
+    on a TPU backend, interpreted elsewhere."""
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -59,5 +64,5 @@ def maxplus_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a.astype(jnp.float32), b.astype(jnp.float32))

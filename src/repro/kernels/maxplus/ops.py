@@ -26,10 +26,9 @@ def dense_adjacency(n: int, edges, pad_to: int = 128) -> np.ndarray:
     return adj
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
 def longest_path_closure(adj: jnp.ndarray, times: jnp.ndarray,
-                         use_pallas: bool = True,
-                         interpret: bool = True) -> jnp.ndarray:
+                         use_pallas: bool = True) -> jnp.ndarray:
     """Finish times for every task of a dense-adjacency DAG.
 
     adj: (p, p) with 0.0 edges / NEG_INF; times: (p,) processing times
@@ -37,8 +36,7 @@ def longest_path_closure(adj: jnp.ndarray, times: jnp.ndarray,
     profitable for batches of small graphs, not one huge sparse graph.
     """
     p = adj.shape[0]
-    mm = (functools.partial(maxplus_matmul, interpret=interpret)
-          if use_pallas else maxplus_matmul_ref)
+    mm = maxplus_matmul if use_pallas else maxplus_matmul_ref
     # W[i,j] = times[i] + adj[i,j]: edge-weighted by the source's duration.
     w = times[:, None] + adj
     # closure by repeated squaring of (I_tropical ⊕ W)
@@ -52,10 +50,9 @@ def longest_path_closure(adj: jnp.ndarray, times: jnp.ndarray,
 
 
 def batched_ranks(adjs: jnp.ndarray, times: jnp.ndarray,
-                  use_pallas: bool = True, interpret: bool = True):
+                  use_pallas: bool = True):
     """Upward ranks for a batch of DAGs: rank = longest path to any sink,
     computed on the reversed graphs.  adjs: (B, p, p); times: (B, p)."""
     rev = jnp.swapaxes(adjs, -1, -2)
-    fn = functools.partial(longest_path_closure, use_pallas=use_pallas,
-                           interpret=interpret)
+    fn = functools.partial(longest_path_closure, use_pallas=use_pallas)
     return jax.vmap(fn)(rev, times)

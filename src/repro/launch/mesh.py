@@ -12,13 +12,21 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_axis: int = 1):
     """A mesh over whatever devices exist (tests / single-host training)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(ndim: int) -> tuple:
+    """Auto axis types: the activation rules place specs with
+    ``with_sharding_constraint``, which Explicit axes (``jax.make_mesh``'s
+    default) refuse."""
+    return (jax.sharding.AxisType.Auto,) * ndim
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

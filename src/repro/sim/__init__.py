@@ -53,8 +53,9 @@ Beyond the paper's static pipeline it adds:
     the device the moment it closes so host building overlaps device
     execution (``pipelined_sweep_makespans``, bit-identical to the serial
     sweep; ``last_pipeline_stats`` reports the measured overlap).
-    ``configure_xla_cache`` points JAX's persistent compilation cache at
-    ``REPRO_XLA_CACHE`` so warm runs skip recompiling entirely;
+    ``configure_xla_cache`` turns on JAX's persistent compilation cache
+    (``JAX_COMPILATION_CACHE_DIR``, else one fixed path in the checkout)
+    so warm runs skip recompiling entirely;
   * **a padded/bucketed JAX path** — ``repro.sim.batch`` evaluates a whole
     heterogeneous campaign of static plans: plans are grouped by the
     power-of-two envelope of (tasks, fan-in), padded to per-bucket maxima,

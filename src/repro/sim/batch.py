@@ -280,8 +280,9 @@ def _one_makespan(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
         finish = finish.at[j].set(start + times[j])
         return finish, ()
 
-    finish0 = jnp.zeros(times.shape[0], dtype=times.dtype)
-    finish, _ = jax.lax.scan(step, finish0, dag.order)
+    # zeros_like, not zeros: under shard_map the carry must inherit the
+    # varying mesh axes of ``times`` or the scan's carry types disagree
+    finish, _ = jax.lax.scan(step, jnp.zeros_like(times), dag.order)
     return jnp.max(finish)
 
 
@@ -556,12 +557,10 @@ def contended_bucket_delays(items: list, networks: list) -> list[np.ndarray]:
     its largest transfer set, so a campaign's contended grid costs at most
     one ``_contended_durations`` compile per bucket; plans with no
     crossing transfers short-circuit to zeros.  The kernel runs under
-    ``jax.experimental.enable_x64()`` so the fixpoint matches the float64
+    ``jax.enable_x64(True)`` so the fixpoint matches the float64
     numpy oracle to rtol 1e-6; the resulting durations scatter back to the
     (deduplicated, output-cached) edges via ``PlanTransfers.key_of``.
     """
-    from jax.experimental import enable_x64
-
     from .engine import plan_times
     from .network import CONTENTION_ITERS, plan_transfers
 
@@ -610,7 +609,7 @@ def contended_bucket_delays(items: list, networks: list) -> list[np.ndarray]:
             t_mask[b, :T] = True
             cap[b] = tr.capacity
         with _obs.span("sim.contended.fixpoint", bucket=f"{n_pad}x{P_pad}",
-                       links=L, plans=B), enable_x64():
+                       links=L, plans=B), jax.enable_x64(True):
             cb = ContendedBucket(
                 order=jnp.asarray(order), pred=jnp.asarray(pred),
                 pred_mask=jnp.asarray(pred >= 0), pred_tid=jnp.asarray(tid),
@@ -691,11 +690,11 @@ def _shard_fn(mesh):
     trace per bucket shape because the wrapped body is the counter)."""
     fn = _SHARD_FNS.get(mesh)
     if fn is None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
         spec = PartitionSpec(_PLAN_AXIS)
-        fn = jax.jit(shard_map(_bucket_makespans.__wrapped__, mesh=mesh,
-                               in_specs=(spec, spec), out_specs=spec))
+        fn = jax.jit(jax.shard_map(_bucket_makespans.__wrapped__,
+                                   mesh=mesh, in_specs=(spec, spec),
+                                   out_specs=spec))
         _SHARD_FNS[mesh] = fn
     return fn
 

@@ -32,8 +32,9 @@ phases instead:
      :func:`last_pipeline_stats` reports the measured ``overlap_frac``.
 
   4. **Persistent XLA compilation cache** — :func:`configure_xla_cache`
-     points ``jax_compilation_cache_dir`` at ``REPRO_XLA_CACHE`` so warm
-     campaign runs skip recompilation entirely.
+     keeps compiled programs where ``JAX_COMPILATION_CACHE_DIR`` says, or
+     at one fixed path in the checkout, so warm campaign runs skip
+     recompilation entirely.
 
 Because buckets pad to the *envelope* (every legal plan of (g, machine)
 fits), the whole pipeline still costs <= 1 XLA compile per bucket
@@ -80,21 +81,31 @@ def plan_workers() -> int:
     return max(1, os.cpu_count() or 1)
 
 
-def configure_xla_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``path`` (default: the
-    ``REPRO_XLA_CACHE`` env var), so warm campaign runs skip recompiling
-    the bucketed kernels entirely.  Returns the directory in effect, or
-    ``None`` when the knob is unset (native ``JAX_COMPILATION_CACHE_DIR``
-    handling still applies then).  Minimum compile time / entry size are
-    zeroed: campaign buckets are many small programs, and the whole point
-    is to skip *all* of them on the second run."""
-    path = path if path is not None else os.environ.get("REPRO_XLA_CACHE", "")
-    if not path:
-        return None
+#: The persistent compilation cache's one fixed home when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: ``.jax_cache`` at the root of the
+#: checkout (git-ignored).  The directory is part of the cache key, so it
+#: never moves between runs.
+XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def configure_xla_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    that directory stands; otherwise the cache goes to :data:`XLA_CACHE_DIR`.
+    Minimum compile time / entry size are zeroed either way: campaign
+    buckets are many small programs, and a warm run should skip *all* of
+    them.  Entry points (``benchmarks.run``, ``chip_smoke.py``) call this
+    before their first compile; importing the package never does."""
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        path = XLA_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
@@ -283,13 +294,25 @@ def _pool_kind(scheduler) -> str:
 # matters twice over: workers must never fork the parent directly (forking
 # a process with live JAX/XLA threads can deadlock) and must not re-import
 # ``__main__`` (``spawn`` breaks under REPLs and unguarded scripts) — the
-# forkserver is a cleanly exec'd interpreter that forks *itself*.
+# forkserver is a cleanly exec'd interpreter that forks *itself*.  Workers
+# are pinned to JAX's CPU backend (``_host_only_worker``): an accelerator
+# belongs to one process, and the parent that dispatches the buckets holds it.
 _PROCESS_POOL: ProcessPoolExecutor | None = None
 _PROCESS_POOL_SIZE = 0
 # flipped after a BrokenProcessPool (e.g. an unguarded/REPL __main__ that
 # the start method cannot re-import): LP-heavy work then routes to the
 # thread pool for the rest of the session instead of re-breaking per sweep
 _PROCESS_POOL_DISABLED = False
+
+
+def _host_only_worker() -> None:
+    """Process-pool initializer: whatever a worker's ``allocate`` touches
+    in JAX initializes the CPU backend, never the parent's accelerator.
+    (Unpickling this function imported jax already, so the environment is
+    read no more: the config is set directly.)"""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _process_pool(workers: int) -> ProcessPoolExecutor:
@@ -301,7 +324,8 @@ def _process_pool(workers: int) -> ProcessPoolExecutor:
             multiprocessing.get_all_start_methods() else "spawn"
         _PROCESS_POOL = ProcessPoolExecutor(
             max_workers=workers,
-            mp_context=multiprocessing.get_context(ctx))
+            mp_context=multiprocessing.get_context(ctx),
+            initializer=_host_only_worker)
         _PROCESS_POOL_SIZE = workers
     return _PROCESS_POOL
 

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode on
+the CPU backend, by the wrappers' own backend rule)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +10,20 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.maxplus import ops as mops
 from repro.kernels.maxplus.maxplus import maxplus_matmul
 from repro.kernels.maxplus.ref import maxplus_matmul_ref
+
+
+def test_interpret_mode_only_off_tpu(monkeypatch):
+    """One rule in every wrapper: interpret on the CPU backend, compile on a
+    TPU backend, and refuse an explicit interpret request there."""
+    from repro import kernels
+    assert jax.default_backend() == "cpu"
+    assert kernels.resolve_interpret(None) is True
+    assert kernels.resolve_interpret(False) is False
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    assert kernels.resolve_interpret(None) is False
+    assert kernels.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret mode on a TPU"):
+        kernels.resolve_interpret(True)
 
 
 @pytest.mark.parametrize("m,k,n", [(128, 128, 128), (256, 128, 384),

@@ -31,11 +31,11 @@ def _random_transfer_set(rng, T):
 
 
 def _kernel_finishes(starts, sizes, up, dn, capacity):
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     T = len(starts)
-    with enable_x64():
+    with jax.enable_x64(True):
         fin = fluid_finishes_jax(jnp.asarray(starts), jnp.asarray(sizes),
                                  jnp.asarray(up), jnp.asarray(dn),
                                  jnp.ones(T, bool), capacity, len(LINKS))

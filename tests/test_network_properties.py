@@ -103,8 +103,8 @@ def test_jitted_fluid_kernel_matches_numpy_oracle(ts, cap):
     fixed-start max-min fluid sub-problem to rtol 1e-6 (satellite of the
     whole-bucket contention fixpoint — ``fluid_finishes_jax`` is what the
     batched path runs per fixpoint round)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.sim.network import _fluid_finishes, fluid_finishes_jax
 
@@ -112,7 +112,7 @@ def test_jitted_fluid_kernel_matches_numpy_oracle(ts, cap):
     starts, sizes = np.asarray(starts), np.asarray(sizes)
     links = [(LINKS[u], LINKS[d]) for u, d in zip(ups, dns)]
     want = _fluid_finishes(starts, sizes, links, cap)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(fluid_finishes_jax(
             jnp.asarray(starts), jnp.asarray(sizes), jnp.asarray(ups),
             jnp.asarray(dns), jnp.ones(len(starts), bool), cap, len(LINKS)))

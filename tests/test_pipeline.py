@@ -17,6 +17,8 @@ bucketing with dispatch-on-close — is observable in the results:
       counters account for every cacheable allocation.
 """
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -249,18 +251,53 @@ def test_process_pool_parity(monkeypatch):
     assert _obs.counter_value("plan_pool.broken") == broken0
 
 
-def test_configure_xla_cache(tmp_path, monkeypatch):
+def test_process_pool_workers_stay_on_cpu():
+    """A parent that holds an accelerator must never see its plan workers
+    initialize one: with ``JAX_PLATFORMS`` naming the TPU in the parent's
+    environment, a fresh pool's worker still comes up on the CPU backend."""
+    code = ("import jax\n"
+            "from repro.sim.pipeline import _process_pool\n"
+            "if __name__ == '__main__':\n"
+            "    print(_process_pool(1).submit(jax.default_backend).result())\n")
+    env = dict(os.environ, JAX_PLATFORMS="tpu", PYTHONPATH="src")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=120,
+                          cwd=os.path.join(os.path.dirname(__file__), ".."))
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split()[-1] == "cpu"
+
+
+@pytest.mark.parametrize("case", ["env_dir_stands", "fixed_default",
+                                  "same_path_every_call"])
+def test_configure_xla_cache(case, tmp_path, monkeypatch):
     import jax
 
-    old = jax.config.jax_compilation_cache_dir
-    target = os.path.join(str(tmp_path), "xla")
+    from repro.sim.pipeline import XLA_CACHE_DIR
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
     try:
-        monkeypatch.setenv("REPRO_XLA_CACHE", target)
-        path = configure_xla_cache()
-        assert path == target and os.path.isdir(target)
-        assert jax.config.jax_compilation_cache_dir == target
-        monkeypatch.delenv("REPRO_XLA_CACHE")
-        assert configure_xla_cache() is None   # unset knob: no-op
-        assert configure_xla_cache("") is None
+        if case == "env_dir_stands":
+            target = os.path.join(str(tmp_path), "xla")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+            assert configure_xla_cache() == target
+            # JAX's own handling of the variable stands: no other directory
+            assert jax.config.jax_compilation_cache_dir == \
+                old["jax_compilation_cache_dir"]
+        elif case == "fixed_default":
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = configure_xla_cache()
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == XLA_CACHE_DIR == os.path.join(root, ".jax_cache")
+            assert os.path.isdir(path)
+            assert jax.config.jax_compilation_cache_dir == path
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert configure_xla_cache() == configure_xla_cache() \
+                == XLA_CACHE_DIR
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+        for k, v in old.items():
+            jax.config.update(k, v)
