@@ -1,0 +1,5 @@
+"""Plans allocated and scored in the window, over the window's length."""
+
+
+def read(run):
+    return run.player.attempted / run.window_s
