@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import registry as _obs
+
 from .allocation import AllocationProblem, frac_objective
 from .dag import CPU, GPU, TaskGraph
 from .hlp import HLPSolution, canonical_round, canonical_round_moldable
@@ -124,14 +126,16 @@ def _solve(d: PaddedDag, m: int, k: int, iters: int, seed: int):
 
     def lam_exact(x):
         times = d.pc * x + d.pg * (1.0 - x)
-        cp = hard_longest_path(d, times)
+        with jax.named_scope("lp_exact_path"):
+            cp = hard_longest_path(d, times)
         return jnp.maximum(cp, jnp.maximum(jnp.dot(d.pc, x) / m,
                                            jnp.dot(d.pg, 1.0 - x) / k))
 
     def loss(z, tau):
         x = jax.nn.sigmoid(z)
         times = d.pc * x + d.pg * (1.0 - x)
-        cp = soft_longest_path(d, times, tau)
+        with jax.named_scope("lp_soft_path"):
+            cp = soft_longest_path(d, times, tau)
         terms = jnp.stack([cp, jnp.dot(d.pc, x) / m, jnp.dot(d.pg, 1.0 - x) / k])
         mx = jnp.max(terms)
         return mx + tau * jnp.log(jnp.sum(jnp.exp((terms - mx) / tau)))
@@ -162,8 +166,9 @@ def _solve(d: PaddedDag, m: int, k: int, iters: int, seed: int):
 
     init = (z0, jnp.zeros(n), jnp.zeros(n), jax.nn.sigmoid(z0),
             lam_exact(jax.nn.sigmoid(z0)))
-    (z, _, _, best_x, best_val), _ = jax.lax.scan(
-        body, init, jnp.arange(iters, dtype=jnp.int32))
+    with jax.named_scope("lp_adam"):
+        (z, _, _, best_x, best_val), _ = jax.lax.scan(
+            body, init, jnp.arange(iters, dtype=jnp.int32))
     return best_x, best_val
 
 
@@ -331,8 +336,9 @@ def solve_hlp_jax(g: TaskGraph, m: int, k: int, iters: int = 400,
         return HLPSolution(x_frac=x, lp_value=float(val), alloc=alloc,
                            status="first-order")
     d = PaddedDag.from_graph(g)
-    x, val = _solve(d, int(m), int(k), int(iters), int(seed))
-    x = np.asarray(x, dtype=np.float64)
+    with _obs.span("lp.device_solve", n=g.n, iters=int(iters)):
+        x, val = _solve(d, int(m), int(k), int(iters), int(seed))
+        x = np.asarray(x, dtype=np.float64)   # blocks until the solve ends
     # λ(x) is exact for the returned iterate -> a *feasible* LP objective.
     val = g.lp_objective([m, k], x)
     alloc = (canonical_round(g, m, k, x) if canonical

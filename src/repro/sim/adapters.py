@@ -88,6 +88,13 @@ def _record_lp_provenance(name: str, g: TaskGraph, machine, sol, *,
             comm_price=float(paid[j]), priced_comm=float(priced[j])))
 
 
+def _phase(name: str, sched):
+    """Span of one plan-build phase: ``plan.allocate`` (the allocation LP,
+    or a single-phase heuristic such as HEFT) or ``plan.order`` (list
+    scheduling of a decided allocation)."""
+    return _obs.span(name, scheduler=sched.name)
+
+
 class StaticScheduler:
     """Base: wrap a ``(g, machine) -> Schedule`` solver into the protocol.
 
@@ -125,7 +132,10 @@ class HLPESTScheduler(StaticScheduler):
         return sol.alloc
 
     def _solve(self, g, machine):
-        return hlp_est(g, machine, self._allocate_lp(g, machine))
+        with _phase("plan.allocate", self):
+            alloc = self._allocate_lp(g, machine)
+        with _phase("plan.order", self):
+            return hlp_est(g, machine, alloc)
 
 
 class HLPOLSScheduler(HLPESTScheduler):
@@ -134,7 +144,10 @@ class HLPOLSScheduler(HLPESTScheduler):
     name = "hlp_ols"
 
     def _solve(self, g, machine):
-        return hlp_ols(g, machine, self._allocate_lp(g, machine))
+        with _phase("plan.allocate", self):
+            alloc = self._allocate_lp(g, machine)
+        with _phase("plan.order", self):
+            return hlp_ols(g, machine, alloc)
 
 
 class HLPJaxOLSScheduler(HLPOLSScheduler):
@@ -187,8 +200,10 @@ class CommAwareHLPScheduler(StaticScheduler):
         return sol.alloc
 
     def _solve(self, g, machine):
-        return hlp_ols(g, machine, self._allocate_lp(g, machine),
-                       comm_tiebreak=True)
+        with _phase("plan.allocate", self):
+            alloc = self._allocate_lp(g, machine)
+        with _phase("plan.order", self):
+            return hlp_ols(g, machine, alloc, comm_tiebreak=True)
 
 
 class CommAwareMoldableScheduler(StaticScheduler):
@@ -210,11 +225,14 @@ class CommAwareMoldableScheduler(StaticScheduler):
         if g.max_width == 1:
             return CommAwareHLPScheduler(
                 contention=self.contention)._solve(g, machine)
-        sol = solve_mhlp(g, machine, comm_aware=True,
-                         contention=self.contention)
-        _record_lp_provenance(self.name, g, machine, sol, comm_aware=True,
-                              contention=self.contention)
-        return hlp_ols(g, machine, sol.alloc, sol.width, comm_tiebreak=True)
+        with _phase("plan.allocate", self):
+            sol = solve_mhlp(g, machine, comm_aware=True,
+                             contention=self.contention)
+            _record_lp_provenance(self.name, g, machine, sol,
+                                  comm_aware=True, contention=self.contention)
+        with _phase("plan.order", self):
+            return hlp_ols(g, machine, sol.alloc, sol.width,
+                           comm_tiebreak=True)
 
 
 class MoldableHLPScheduler(StaticScheduler):
@@ -234,9 +252,11 @@ class MoldableHLPScheduler(StaticScheduler):
     def _solve(self, g, machine):
         if g.max_width == 1:
             return HLPOLSScheduler()._solve(g, machine)
-        sol = solve_mhlp(g, machine)
-        _record_lp_provenance(self.name, g, machine, sol)
-        return hlp_ols(g, machine, sol.alloc, sol.width)
+        with _phase("plan.allocate", self):
+            sol = solve_mhlp(g, machine)
+            _record_lp_provenance(self.name, g, machine, sol)
+        with _phase("plan.order", self):
+            return hlp_ols(g, machine, sol.alloc, sol.width)
 
 
 class HEFTScheduler(StaticScheduler):
@@ -245,7 +265,8 @@ class HEFTScheduler(StaticScheduler):
     name = "heft"
 
     def _solve(self, g, machine):
-        return heft(g, machine)
+        with _phase("plan.allocate", self):
+            return heft(g, machine)
 
 
 class HEFTObliviousScheduler(StaticScheduler):
@@ -258,7 +279,8 @@ class HEFTObliviousScheduler(StaticScheduler):
     name = "heft_nocomm"
 
     def _solve(self, g, machine):
-        return heft(g, machine, comm_aware=False)
+        with _phase("plan.allocate", self):
+            return heft(g, machine, comm_aware=False)
 
 
 class BruteForceScheduler(StaticScheduler):

@@ -70,51 +70,29 @@ from .engine import Machine, NoiseModel, Plan
 #: Compile-count kinds tracked by the jitted evaluators.  The increments
 #: live *inside* the jitted function bodies, so each advances once per XLA
 #: trace (shape bucket), not once per call.  Tests assert <= 1 per bucket.
-#: The counts live in the ``repro.obs`` registry under
-#: ``sim.compile.<kind>``; ``_TRACES`` remains as a thin mapping shim for
-#: code that still reads/writes the old module global.
+#: The counts are the ``repro.obs`` counters ``sim.compile.<kind>``.
 TRACE_KINDS = ("bucket", "single", "contended")
 
 
-class _TraceShim:
-    """Mapping view over the obs-registry compile counters (legacy
-    ``_TRACES`` interface)."""
-
-    @staticmethod
-    def _key(kind: str) -> str:
-        if kind not in TRACE_KINDS:
-            raise ValueError(f"unknown trace kind {kind!r}; "
-                             f"valid kinds: {', '.join(TRACE_KINDS)}")
-        return f"sim.compile.{kind}"
-
-    def __getitem__(self, kind: str) -> int:
-        return _obs.counter_value(self._key(kind))
-
-    def __setitem__(self, kind: str, value: int) -> None:
-        _obs.set_counter(self._key(kind), value)
-
-    def __iter__(self):
-        return iter(TRACE_KINDS)
-
-    def items(self):
-        return [(k, self[k]) for k in TRACE_KINDS]
-
-
-_TRACES = _TraceShim()
+def _compile_counter(kind: str) -> str:
+    if kind not in TRACE_KINDS:
+        raise ValueError(f"unknown trace kind {kind!r}; "
+                         f"valid kinds: {', '.join(TRACE_KINDS)}")
+    return f"sim.compile.{kind}"
 
 
 def trace_count(kind: str = "bucket") -> int:
     """XLA traces of the ``kind`` evaluator since process start (or the
     last :func:`reset_trace_counts`).  Raises ``ValueError`` on unknown
     kinds, listing the valid ones."""
-    return _TRACES[kind]
+    return _obs.counter_value(_compile_counter(kind))
 
 
 def reset_trace_counts() -> None:
     """Zero every compile counter — test setup, so assertions read absolute
     counts instead of hand-rolled before/after deltas."""
     for kind in TRACE_KINDS:
-        _TRACES[kind] = 0
+        _obs.set_counter(_compile_counter(kind), 0)
 
 
 # ---------------------------------------------------------------- plan DAGs
@@ -461,8 +439,9 @@ def _bucket_makespans(bd: BatchedPlanDag, times: jnp.ndarray) -> jnp.ndarray:
                                 PlanDag(order, pred, mask, delay, floor,
                                         width)))(t)
 
-    return jax.vmap(per_item)(bd.order, bd.pred, bd.pred_mask,
-                              bd.pred_delay, bd.floor, bd.width, times)
+    with jax.named_scope("replay_scan"):
+        return jax.vmap(per_item)(bd.order, bd.pred, bd.pred_mask,
+                                  bd.pred_delay, bd.floor, bd.width, times)
 
 
 # -------------------------------------------------- contended bucket kernel
@@ -534,8 +513,9 @@ def _contended_durations(cb: ContendedBucket, num_links: int,
                              <= 1e-9 + 1e-3 * jnp.abs(dur)) | ~t_mask)
             return jnp.where(done, dur, new), done | close
 
-        dur, _ = jax.lax.fori_loop(0, iters, round_fn,
-                                   (dur0, jnp.array(False)))
+        with jax.named_scope("contention_fixpoint"):
+            dur, _ = jax.lax.fori_loop(0, iters, round_fn,
+                                       (dur0, jnp.array(False)))
         return dur
 
     return jax.vmap(per_plan)(cb.order, cb.pred, cb.pred_mask, cb.pred_tid,

@@ -46,12 +46,15 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import multiprocessing
 import os
 import threading
 import time
-from concurrent.futures import Executor, ProcessPoolExecutor, ThreadPoolExecutor
+from concurrent.futures import (Executor, Future, ProcessPoolExecutor,
+                                ThreadPoolExecutor)
 from concurrent.futures.process import BrokenProcessPool
+from contextlib import nullcontext
 
 import numpy as np
 
@@ -269,12 +272,23 @@ def cached_solve(kind: str, g: TaskGraph, machine, solve, *, extra=()):
 
 
 # ------------------------------------------------- parallel plan construction
-def _allocate_timed(scheduler, g, machine):
-    """Worker-side allocate, returning (plan, solve_seconds).  Top-level so
-    the process pool can pickle it by reference."""
-    t0 = time.perf_counter()
-    plan = scheduler.allocate(g, machine)
-    return plan, time.perf_counter() - t0
+def _allocate_timed(scheduler, g, machine, tags=None):
+    """Worker-side allocate, returning ``(plan, solve_seconds, recorded)``.
+    Top-level so the process pool can pickle it by reference.
+
+    ``tags`` is ``None`` while the parent's registry is disabled; otherwise
+    the task runs under :class:`repro.obs.registry.collect` with those tags,
+    and ``recorded`` carries its spans and decision records back for the
+    parent to merge.  A process worker has a registry of its own, a thread
+    worker shares the parent's: either way the buffer keeps them apart.
+    Span times need no mapping: ``perf_counter`` is ``CLOCK_MONOTONIC``,
+    shared by every process of the host."""
+    scope = _obs.collect(**tags) if tags is not None else nullcontext()
+    with scope as recorded:
+        t0 = time.perf_counter()
+        plan = scheduler.allocate(g, machine)
+        dt = time.perf_counter() - t0
+    return plan, dt, recorded
 
 
 def _pool_kind(scheduler) -> str:
@@ -349,13 +363,14 @@ def build_plans(entries, *, workers: int | None = None, cache: bool = True,
     Deterministic by construction: futures are gathered in submission
     order, every solver is deterministic, and cache hits return the
     original ``Plan`` object — so the result list is bit-identical for any
-    ``workers`` and cache setting.  When obs recording is enabled the build
-    runs serially in-process so span/decision ordering (LP provenance)
-    stays deterministic too.
+    ``workers`` and cache setting.  While obs recording is enabled each
+    worker returns its spans and decision records with its plan
+    (:func:`_allocate_timed`), tagged like the calling thread's spans, and
+    they are merged in submission order: the decision records are the
+    same, in the same order, for any ``workers``.
     """
     workers = plan_workers() if workers is None else max(1, int(workers))
-    if _obs.enabled():
-        workers = 1
+    tags = _obs.tags() if _obs.enabled() else None
     results: list = [None] * len(entries)
     build_s = 0.0
 
@@ -407,15 +422,16 @@ def build_plans(entries, *, workers: int | None = None, cache: bool = True,
                         continue
             kind = _pool_kind(sched)
             if kind == "serial":
-                plan, dt = _allocate_timed(sched, g, machine)
-                build_s += dt
-                results[i] = plan
+                # inline, but gathered in order with the pooled results
+                futures[i] = Future()
+                futures[i].set_result(_allocate_timed(sched, g, machine,
+                                                      tags))
             else:
                 futures[i] = pool_for(kind).submit(
-                    _allocate_timed, sched, g, machine)
+                    _allocate_timed, sched, g, machine, tags)
         for i, fut in futures.items():
             try:
-                plan, dt = fut.result()
+                plan, dt, recorded = fut.result()
             except BrokenProcessPool:
                 # a spawn-hostile __main__ (stdin/REPL) or a killed worker:
                 # solvers are deterministic, so recomputing inline keeps
@@ -423,7 +439,10 @@ def build_plans(entries, *, workers: int | None = None, cache: bool = True,
                 _reset_process_pool(disable=True)
                 _obs.bump("plan_pool.broken")
                 g_i, machine_i, sched_i = entries[i]
-                plan, dt = _allocate_timed(sched_i, g_i, machine_i)
+                plan, dt, recorded = _allocate_timed(sched_i, g_i, machine_i,
+                                                     tags)
+            if recorded is not None:
+                _obs.merge(recorded)
             build_s += dt
             results[i] = plan
             key = keys[i]
@@ -450,7 +469,10 @@ class PipelineStats:
     buckets: int = 0
     workers: int = 1
     plan_build_s: float = 0.0    # summed solver seconds (all workers)
-    dispatch_s: float = 0.0      # host-side bucket build + async dispatch
+    dispatch_s: float = 0.0      # the dispatch loop: the three below
+    sample_s: float = 0.0        # noise grids of every plan
+    assemble_s: float = 0.0      # plan-DAG assembly, padding, stacking
+    launch_s: float = 0.0        # device transfer + async bucket dispatch
     drain_s: float = 0.0         # blocking device sync at the end
     total_s: float = 0.0
     overlap_s: float = 0.0       # host work done with >= 1 bucket in flight
@@ -460,6 +482,7 @@ class PipelineStats:
 
 
 _LAST_STATS = PipelineStats()
+_CALLS = itertools.count(1)   # the ``call`` tag of each sweep's spans
 
 
 def last_pipeline_stats() -> PipelineStats:
@@ -516,58 +539,71 @@ def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
         members.setdefault(key, []).append(i)
     stats.buckets = len(members)
 
-    with _obs.span("sim.pipeline.build", plans=len(entries),
-                   buckets=len(members), workers=stats.workers):
-        plans, stats.plan_build_s = build_plans(
-            entries, workers=workers, cache=cache, network=None)
-    for (g, machine, scheduler), plan in zip(entries, plans):
-        if plan is None:
-            raise ValueError(f"{scheduler.name} is arrival-driven; "
-                             "the batch path needs a static plan")
-
-    pending = {key: len(idxs) for key, idxs in members.items()}
-    grids: dict[int, np.ndarray] = {}
-    in_flight: list[tuple[tuple[int, int], list[int], object]] = []
-    first_dispatch = None
-    t_disp0 = time.perf_counter()
-    for i, ((g, machine, _), plan) in enumerate(zip(entries, plans)):
-        grids[i] = np.asarray(sample_fn(g, plan) if sample_fn is not None
-                              else sample_actual_batch(g, plan, noise, seeds),
-                              dtype=np.float64)
-        key = keys[i]
-        pending[key] -= 1
-        if pending[key]:
-            continue
-        idxs = members[key]
-        with _obs.span("sim.pipeline.dispatch", bucket=f"{key[0]}x{key[1]}",
-                       plans=len(idxs)):
-            items = [(entries[j][0], plans[j]) for j in idxs]
-            bd = BatchedPlanDag.from_plans(
-                items, pad_to=key,
-                floors=([np.asarray(floor_fn(entries[j][0], plans[j]),
-                                    dtype=np.float64) for j in idxs]
-                        if floor_fn is not None else None),
-                networks=([networks[j] for j in idxs]
-                          if networks is not None else None))
-            if (bd.n_pad, bd.pred.shape[2]) != key:
-                raise AssertionError(
-                    f"plan escaped its envelope {key}: bucket padded to "
-                    f"{(bd.n_pad, bd.pred.shape[2])}")
-            tt = np.stack([_pad_times(grids.pop(j), bd.n_pad) for j in idxs])
-            # async dispatch: the device starts here, the host moves on
-            ms = _bucket_makespans_sharded(bd, jnp.asarray(tt), mesh=mesh)
-        in_flight.append((key, idxs, ms))
-        if first_dispatch is None:
-            first_dispatch = time.perf_counter()
-    t_drain0 = time.perf_counter()
-    stats.dispatch_s = t_drain0 - t_disp0
-
-    out: list[np.ndarray | None] = [None] * len(entries)
-    with _obs.span("sim.pipeline.drain", buckets=len(in_flight)):
-        for key, idxs, ms in in_flight:
-            ms = np.asarray(ms)   # blocks until this bucket's device work ends
-            for row, j in enumerate(idxs):
-                out[j] = ms[row]
+    with _obs.tagged(call=next(_CALLS)):
+        with _obs.span("sim.pipeline.build", plans=len(entries),
+                       buckets=len(members), workers=stats.workers):
+            plans, stats.plan_build_s = build_plans(
+                entries, workers=workers, cache=cache, network=None)
+        for (g, machine, scheduler), plan in zip(entries, plans):
+            if plan is None:
+                raise ValueError(f"{scheduler.name} is arrival-driven; "
+                                 "the batch path needs a static plan")
+        # the dispatch loop: one timer per part, each both the span and the
+        # ``PipelineStats`` counter it adds to, so the two cannot disagree
+        pending = {key: len(idxs) for key, idxs in members.items()}
+        grids: dict[int, np.ndarray] = {}
+        in_flight: list[tuple[tuple[int, int], list[int], object]] = []
+        first_dispatch = None
+        with _obs.timer("sim.pipeline.dispatch", plans=len(entries),
+                        buckets=len(members)) as disp:
+            for i, ((g, machine, _), plan) in enumerate(zip(entries, plans)):
+                with _obs.timer("sim.pipeline.sample", plan=i) as t:
+                    grids[i] = np.asarray(
+                        sample_fn(g, plan) if sample_fn is not None
+                        else sample_actual_batch(g, plan, noise, seeds),
+                        dtype=np.float64)
+                stats.sample_s += t.dur
+                key = keys[i]
+                pending[key] -= 1
+                if pending[key]:
+                    continue
+                idxs = members[key]
+                bucket = f"{key[0]}x{key[1]}"
+                with _obs.timer("sim.pipeline.assemble", bucket=bucket,
+                                plans=len(idxs)) as t:
+                    items = [(entries[j][0], plans[j]) for j in idxs]
+                    bd = BatchedPlanDag.from_plans(
+                        items, pad_to=key,
+                        floors=([np.asarray(floor_fn(g_j, p_j),
+                                            dtype=np.float64)
+                                 for g_j, p_j in items]
+                                if floor_fn is not None else None),
+                        networks=([networks[j] for j in idxs]
+                                  if networks is not None else None))
+                    if (bd.n_pad, bd.pred.shape[2]) != key:
+                        raise AssertionError(
+                            f"plan escaped its envelope {key}: bucket "
+                            f"padded to {(bd.n_pad, bd.pred.shape[2])}")
+                    tt = np.stack([_pad_times(grids.pop(j), bd.n_pad)
+                                   for j in idxs])
+                stats.assemble_s += t.dur
+                with _obs.timer("sim.pipeline.launch", bucket=bucket) as t:
+                    # async dispatch: the device starts here, the host
+                    # moves on
+                    ms = _bucket_makespans_sharded(bd, jnp.asarray(tt),
+                                                   mesh=mesh)
+                stats.launch_s += t.dur
+                in_flight.append((key, idxs, ms))
+                if first_dispatch is None:
+                    first_dispatch = time.perf_counter()
+        stats.dispatch_s = disp.dur
+        t_drain0 = disp.t0 + disp.dur
+        out: list[np.ndarray | None] = [None] * len(entries)
+        with _obs.span("sim.pipeline.drain", buckets=len(in_flight)):
+            for key, idxs, ms in in_flight:
+                ms = np.asarray(ms)   # blocks until this bucket's work ends
+                for row, j in enumerate(idxs):
+                    out[j] = ms[row]
     t_end = time.perf_counter()
     stats.drain_s = t_end - t_drain0
     stats.total_s = t_end - t_start
@@ -577,7 +613,5 @@ def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
         else 0.0
     stats.cache_hits = _obs.counter_value("plan_cache.hits") - hits0
     stats.cache_misses = _obs.counter_value("plan_cache.misses") - misses0
-    _obs.set_gauge("sim.pipeline.overlap_frac", stats.overlap_frac)
-    _obs.set_gauge("sim.pipeline.plan_build_s", stats.plan_build_s)
     _LAST_STATS = stats
     return out  # type: ignore[return-value]
