@@ -88,13 +88,6 @@ def _record_lp_provenance(name: str, g: TaskGraph, machine, sol, *,
             comm_price=float(paid[j]), priced_comm=float(priced[j])))
 
 
-def _phase(name: str, sched):
-    """Span of one plan-build phase: ``plan.allocate`` (the allocation LP,
-    or a single-phase heuristic such as HEFT) or ``plan.order`` (list
-    scheduling of a decided allocation)."""
-    return _obs.span(name, scheduler=sched.name)
-
-
 class StaticScheduler:
     """Base: wrap a ``(g, machine) -> Schedule`` solver into the protocol.
 
@@ -102,7 +95,12 @@ class StaticScheduler:
     executor (``repro.sim.pipeline``): ``"process"`` for the HiGHS/LP-heavy
     solvers that hold the GIL, ``"thread"`` for pure-numpy or JAX-backed
     ones that must stay in-process.  ``cacheable = False`` opts an adapter
-    out of the content-addressed plan cache."""
+    out of the content-addressed plan cache.
+
+    ``_solve`` records its phases as spans tagged ``scheduler=``:
+    ``plan.allocate`` (the allocation LP, or all of a single-phase
+    heuristic such as HEFT) and ``plan.order`` (list scheduling of the
+    decided allocation)."""
 
     name = "static"
     plan_pool = "thread"
@@ -132,9 +130,9 @@ class HLPESTScheduler(StaticScheduler):
         return sol.alloc
 
     def _solve(self, g, machine):
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             alloc = self._allocate_lp(g, machine)
-        with _phase("plan.order", self):
+        with _obs.span("plan.order", scheduler=self.name):
             return hlp_est(g, machine, alloc)
 
 
@@ -144,9 +142,9 @@ class HLPOLSScheduler(HLPESTScheduler):
     name = "hlp_ols"
 
     def _solve(self, g, machine):
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             alloc = self._allocate_lp(g, machine)
-        with _phase("plan.order", self):
+        with _obs.span("plan.order", scheduler=self.name):
             return hlp_ols(g, machine, alloc)
 
 
@@ -200,9 +198,9 @@ class CommAwareHLPScheduler(StaticScheduler):
         return sol.alloc
 
     def _solve(self, g, machine):
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             alloc = self._allocate_lp(g, machine)
-        with _phase("plan.order", self):
+        with _obs.span("plan.order", scheduler=self.name):
             return hlp_ols(g, machine, alloc, comm_tiebreak=True)
 
 
@@ -225,12 +223,12 @@ class CommAwareMoldableScheduler(StaticScheduler):
         if g.max_width == 1:
             return CommAwareHLPScheduler(
                 contention=self.contention)._solve(g, machine)
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             sol = solve_mhlp(g, machine, comm_aware=True,
                              contention=self.contention)
             _record_lp_provenance(self.name, g, machine, sol,
                                   comm_aware=True, contention=self.contention)
-        with _phase("plan.order", self):
+        with _obs.span("plan.order", scheduler=self.name):
             return hlp_ols(g, machine, sol.alloc, sol.width,
                            comm_tiebreak=True)
 
@@ -252,10 +250,10 @@ class MoldableHLPScheduler(StaticScheduler):
     def _solve(self, g, machine):
         if g.max_width == 1:
             return HLPOLSScheduler()._solve(g, machine)
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             sol = solve_mhlp(g, machine)
             _record_lp_provenance(self.name, g, machine, sol)
-        with _phase("plan.order", self):
+        with _obs.span("plan.order", scheduler=self.name):
             return hlp_ols(g, machine, sol.alloc, sol.width)
 
 
@@ -265,7 +263,7 @@ class HEFTScheduler(StaticScheduler):
     name = "heft"
 
     def _solve(self, g, machine):
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             return heft(g, machine)
 
 
@@ -279,7 +277,7 @@ class HEFTObliviousScheduler(StaticScheduler):
     name = "heft_nocomm"
 
     def _solve(self, g, machine):
-        with _phase("plan.allocate", self):
+        with _obs.span("plan.allocate", scheduler=self.name):
             return heft(g, machine, comm_aware=False)
 
 
