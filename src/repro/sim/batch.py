@@ -55,7 +55,8 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from collections import defaultdict
+import threading
+from collections import OrderedDict, defaultdict
 from functools import partial
 
 import jax
@@ -297,22 +298,72 @@ def batch_makespans(g: TaskGraph, plan: Plan, times: np.ndarray) -> np.ndarray:
     return np.asarray(_batch_makespans(build_plan_dag(g, plan), times))
 
 
+#: Memo of noise multiplier grids, keyed by content: (kind, scale, n, the
+#: seeds as int64 bytes) -> read-only (S, n) float64.  The draw is a pure
+#: function of that key, so every plan of one size under one seed set
+#: (common random numbers) shares one draw.  Bounded in entries and bytes.
+_NOISE_CACHE_ENTRIES = 4
+_NOISE_CACHE_BYTES = 64 << 20
+_noise_cache: OrderedDict[tuple, np.ndarray] = OrderedDict()
+_noise_cache_lock = threading.Lock()
+
+
+def clear_noise_cache() -> None:
+    """Drop every memoised noise grid (test isolation)."""
+    with _noise_cache_lock:
+        _noise_cache.clear()
+
+
+def _noise_multipliers(noise: NoiseModel, seeds: np.ndarray,
+                       n: int) -> np.ndarray:
+    """(S, n) multipliers, row s drawn by ``default_rng(seeds[s])`` exactly
+    as ``noise.sample`` draws it.  The array is the cache's own: read-only,
+    never handed to callers.  Counters: ``noise_draws.hits`` / ``.misses``."""
+    key = (noise.kind, noise.scale, n, seeds.tobytes())
+    with _noise_cache_lock:
+        mult = _noise_cache.get(key)
+        if mult is not None:
+            _noise_cache.move_to_end(key)
+            _obs.bump("noise_draws.hits")
+            return mult
+        _obs.bump("noise_draws.misses")
+    mult = np.empty((len(seeds), n))
+    for row, s in zip(mult, seeds):
+        row[:] = noise.multipliers(n, np.random.default_rng(int(s)))
+    mult.setflags(write=False)
+    if mult.nbytes <= _NOISE_CACHE_BYTES:
+        with _noise_cache_lock:
+            _noise_cache[key] = mult
+            while (len(_noise_cache) > _NOISE_CACHE_ENTRIES
+                   or sum(m.nbytes for m in _noise_cache.values())
+                   > _NOISE_CACHE_BYTES):
+                _noise_cache.popitem(last=False)
+    return mult
+
+
 def sample_actual_batch(g: TaskGraph, plan: Plan, noise: NoiseModel,
                         seeds) -> np.ndarray:
     """(S, n) realized times on each task's allocated type, one row per seed.
 
     Row s uses ``np.random.default_rng(seeds[s])`` exactly like
     ``engine.simulate(..., seed=seeds[s])`` — the two paths see identical
-    noise streams.  Moldable decisions shrink each entry by the task's
-    speedup curve at the plan's width (``engine.plan_times`` semantics).
+    noise streams.  The draw depends only on (noise, seeds, n), so it runs
+    once per such key and is shared across plans (``_noise_multipliers``);
+    each plan then gathers its allocated column in one vectorised step.
+    Moldable decisions shrink each entry by the task's speedup curve at the
+    plan's width (``engine.plan_times`` semantics, same operation order).
     """
-    from .engine import plan_times
-
-    rows = []
-    for s in seeds:
-        actual = noise.sample(g.proc, np.random.default_rng(int(s)))
-        rows.append(plan_times(g, plan, actual))
-    return np.stack(rows)
+    seeds = np.asarray(seeds, dtype=np.int64)
+    idx = np.arange(g.n)
+    base = g.proc[idx, np.asarray(plan.alloc, dtype=np.int64)]
+    if noise.active:
+        rows = base * _noise_multipliers(noise, seeds, g.n)
+    else:
+        rows = np.tile(base, (len(seeds), 1))
+    if plan.width is not None and g.speedup is not None:
+        rows = rows / g.speedup[idx, np.asarray(plan.width,
+                                                dtype=np.int64) - 1]
+    return rows
 
 
 def sweep_makespans(g: TaskGraph, machine: Machine, scheduler, *,

@@ -91,19 +91,26 @@ class NoiseModel:
             raise ValueError("uniform noise needs 0 <= scale < 1, "
                              f"got {self.scale}")
 
-    def sample(self, proc: np.ndarray, rng: np.random.Generator) -> np.ndarray:
-        if self.kind == "none" or self.scale == 0.0:
-            return proc
-        n = proc.shape[0]
+    @property
+    def active(self) -> bool:
+        """Whether a realization draws anything (``none`` and scale 0 do
+        not: actual == estimate)."""
+        return self.kind != "none" and self.scale != 0.0
+
+    def multipliers(self, n: int, rng: np.random.Generator) -> np.ndarray:
+        """(n,) per-task multipliers of one realization — the one draw
+        ``sample`` and ``repro.sim.batch.sample_actual_batch`` share, so
+        both see the same stream for the same generator."""
         if self.kind == "lognormal":
-            mult = rng.lognormal(-0.5 * self.scale ** 2, self.scale, size=n)
-        elif self.kind == "uniform":
-            if not 0.0 <= self.scale < 1.0:
-                raise ValueError("uniform noise needs 0 <= scale < 1")
-            mult = rng.uniform(1.0 - self.scale, 1.0 + self.scale, size=n)
-        else:
-            raise ValueError(f"unknown noise kind {self.kind!r}")
-        return proc * mult[:, None]
+            return rng.lognormal(-0.5 * self.scale ** 2, self.scale, size=n)
+        if self.kind == "uniform":
+            return rng.uniform(1.0 - self.scale, 1.0 + self.scale, size=n)
+        raise ValueError(f"noise kind {self.kind!r} draws nothing")
+
+    def sample(self, proc: np.ndarray, rng: np.random.Generator) -> np.ndarray:
+        if not self.active:
+            return proc
+        return proc * self.multipliers(proc.shape[0], rng)[:, None]
 
 
 # --------------------------------------------------------------------- plan

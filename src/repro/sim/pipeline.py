@@ -479,6 +479,8 @@ class PipelineStats:
     overlap_frac: float = 0.0    # overlap_s / total_s
     cache_hits: int = 0
     cache_misses: int = 0
+    noise_hits: int = 0          # noise grids shared with an earlier plan
+    noise_misses: int = 0        # noise grids drawn
 
 
 _LAST_STATS = PipelineStats()
@@ -522,6 +524,8 @@ def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
                           else max(1, int(workers)))
     hits0 = _obs.counter_value("plan_cache.hits")
     misses0 = _obs.counter_value("plan_cache.misses")
+    noise_hits0 = _obs.counter_value("noise_draws.hits")
+    noise_misses0 = _obs.counter_value("noise_draws.misses")
     if not entries:
         _LAST_STATS = stats
         return []
@@ -613,5 +617,8 @@ def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
         else 0.0
     stats.cache_hits = _obs.counter_value("plan_cache.hits") - hits0
     stats.cache_misses = _obs.counter_value("plan_cache.misses") - misses0
+    stats.noise_hits = _obs.counter_value("noise_draws.hits") - noise_hits0
+    stats.noise_misses = (_obs.counter_value("noise_draws.misses")
+                          - noise_misses0)
     _LAST_STATS = stats
     return out  # type: ignore[return-value]

@@ -18,6 +18,7 @@ from repro import obs
 from repro.obs import registry as _obs
 from repro.sim import NoiseModel, make_scheduler
 from repro.sim import pipeline
+from repro.sim.batch import clear_noise_cache
 from repro.sim.pipeline import (build_plans, clear_plan_cache,
                                 last_pipeline_stats,
                                 pipelined_sweep_makespans, plan_fingerprint)
@@ -33,6 +34,7 @@ PARTS = {"sim.pipeline.sample": "sample_s",
 def _registry_off():
     obs.disable()
     obs.reset(counters=True)
+    clear_noise_cache()   # each test draws its own noise grids
     yield
     obs.disable()
     obs.reset(counters=True)

@@ -10,6 +10,7 @@ The contract under test, in order of importance:
   * the ``evo``/``evo_camhlp`` adapters and the ``search`` bench registry
     entry exist and plug into the standard pipelines.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -66,6 +67,7 @@ def test_bruteforce_exact_match_at_small_n(seed):
 
 def test_whole_search_is_one_xla_compile():
     sc = layered_scenario(n=35, layers=5, seed=5)
+    jax.clear_caches()   # a shape compiled by an earlier test is no retrace
     reset_trace_counts()
     for method in ("ga", "cem", "sa"):
         evolve_plan(sc.graph, sc.machine,
