@@ -9,9 +9,9 @@ where CP(x) is the DAG longest path under fractional lengths
 convex), and the loads are linear.  We minimize f with Adam on logits
 (x = σ(z)), using a temperature-annealed soft longest path for gradient flow
 and tracking the best *exact* iterate.  Everything — including the longest
-path, expressed as a ``lax.scan`` over the topological order — runs jitted,
-so the allocation phase scales to graphs far beyond what the paper solved
-with GLPK (and runs on accelerators).
+path, expressed as a ``lax.scan`` over the DAG's topological levels, one
+step per level — runs jitted, so the allocation phase scales to graphs far
+beyond what the paper solved with GLPK (and runs on accelerators).
 
 Problem data comes from the shared ``repro.core.allocation.AllocationProblem``
 IR — the same (type, width) choice grid, per-choice times, area terms and
@@ -51,73 +51,105 @@ _NEG = -1e30
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class PaddedDag:
-    """Topo-ordered, pred-padded DAG in device arrays (static shapes for jit)."""
-    topo: jnp.ndarray       # (n,)   int32
+    """Pred-padded DAG and its topological levels in device arrays (static
+    shapes for jit)."""
     pred: jnp.ndarray       # (n, P) int32, -1 padded, rows aligned with task ids
     pred_mask: jnp.ndarray  # (n, P) bool
     pc: jnp.ndarray         # (n,) CPU times
     pg: jnp.ndarray         # (n,) GPU times
     pred_comm: jnp.ndarray  # (n, P) transfer cost of each pred edge (0 padded)
+    levels: jnp.ndarray     # (L, W) int32: row l holds the tasks of level l,
+    #                         padded with n
 
     @staticmethod
     def from_graph(g: TaskGraph) -> "PaddedDag":
-        P = max(1, int(np.diff(g.pred_ptr).max()) if g.n else 1)
-        pred = np.full((g.n, P), -1, dtype=np.int32)
-        pcomm = np.zeros((g.n, P), dtype=np.float64)
-        for j in range(g.n):
-            pj = g.preds(j)
-            pred[j, : pj.size] = pj
-            pcomm[j, : pj.size] = g.comm[g.pred_edges(j)]
+        n = g.n
+        deg = np.diff(g.pred_ptr)
+        P = max(1, int(deg.max()) if n else 1)
+        row = np.repeat(np.arange(n), deg)
+        col = np.arange(g.pred_idx.size) - np.repeat(g.pred_ptr[:-1], deg)
+        pred = np.full((n, P), -1, dtype=np.int32)
+        pred[row, col] = g.pred_idx
+        pcomm = np.zeros((n, P), dtype=np.float64)
+        pcomm[row, col] = g.comm[g.pred_eid]
+        count = np.bincount(g.level, minlength=1)
+        by_level = np.argsort(g.level, kind="stable")
+        lane = np.arange(n) - np.repeat(np.cumsum(count) - count, count)
+        levels = np.full((count.size, int(count.max())), n, dtype=np.int32)
+        levels[g.level[by_level], lane] = by_level
         return PaddedDag(
-            topo=jnp.asarray(g.topo), pred=jnp.asarray(pred),
-            pred_mask=jnp.asarray(pred >= 0),
+            pred=jnp.asarray(pred), pred_mask=jnp.asarray(pred >= 0),
             pc=jnp.asarray(g.proc[:, CPU]), pg=jnp.asarray(g.proc[:, GPU]),
-            pred_comm=jnp.asarray(pcomm))
+            pred_comm=jnp.asarray(pcomm), levels=jnp.asarray(levels))
+
+
+def _level_scan(d: PaddedDag, times: jnp.ndarray,
+                edge_delay: jnp.ndarray | None, start) -> jnp.ndarray:
+    """Finish times, in task order, from one scan step per topological level.
+
+    A level's tasks depend only on earlier levels, so each step finishes a
+    whole level at once from already-final predecessor finishes.  The carry
+    is level-major, (L, W): task ``levels[l, i]`` finishes in slot (l, i),
+    and padding lanes, with no preds and zero time, finish at 0.
+    ``start(pf, mask)`` maps the (P, W) predecessor finishes of a level
+    (``edge_delay`` added) to its W start times.
+    """
+    n = times.shape[0]
+    L, W = d.levels.shape
+    lanes = d.levels.reshape(-1)
+    real = lanes < n
+    task = jnp.where(real, lanes, 0)
+    slot = jnp.zeros(n, jnp.int32).at[lanes].set(
+        jnp.arange(L * W, dtype=jnp.int32), mode="drop")
+
+    def by_level(a):                    # (L·W, P) -> (L, P, W)
+        return a.reshape(L, W, -1).transpose(0, 2, 1)
+
+    xs = (jnp.arange(L),
+          by_level(slot[jnp.maximum(d.pred[task], 0)]),
+          by_level(d.pred_mask[task] & real[:, None]),
+          jnp.where(real, times[task], 0.0).reshape(L, W),
+          None if edge_delay is None else by_level(edge_delay[task]))
+
+    def step(finish, x):
+        lvl, pred_slot, mask, t, delay = x
+        pf = finish.reshape(-1)[pred_slot]
+        if delay is not None:
+            pf = pf + delay
+        return finish.at[lvl].set(start(pf, mask) + t), ()
+
+    finish, _ = jax.lax.scan(step, jnp.zeros((L, W), times.dtype), xs)
+    return finish.reshape(-1)[slot]
 
 
 def soft_longest_path(d: PaddedDag, times: jnp.ndarray, tau: jnp.ndarray,
                       edge_delay: jnp.ndarray | None = None) -> jnp.ndarray:
     """Temperature-τ softmax-relaxed longest path; τ→0 recovers the exact CP.
 
-    Runs as a scan over the topological order: each step finishes one task
-    from the (already final) finish times of its predecessors.
     ``edge_delay`` optionally adds an (n, P) per-pred-slot delay (the
     comm-augmented edge delays of the comm-aware solvers); ``None`` traces
     the historical delay-free graph.
     """
 
-    def step(finish, j):
-        pf = finish[d.pred[j]]
-        if edge_delay is not None:
-            pf = pf + edge_delay[j]
-        pf = jnp.where(d.pred_mask[j], pf, _NEG)
+    def start(pf, mask):
+        pf = jnp.where(mask, pf, _NEG)
         # soft-max over predecessors (upper-bounds the hard max by τ·log P).
-        m = jnp.max(pf)
-        has_pred = jnp.any(d.pred_mask[j])
-        soft = m + tau * jnp.log(jnp.sum(jnp.exp((pf - m) / tau)) + 1e-30) * 1.0
-        start = jnp.where(has_pred, jnp.maximum(soft, 0.0), 0.0)
-        finish = finish.at[j].set(start + times[j])
-        return finish, ()
+        m = jnp.max(pf, axis=0)
+        soft = m + tau * jnp.log(jnp.sum(jnp.exp((pf - m) / tau), axis=0)
+                                 + 1e-30)
+        return jnp.where(jnp.any(mask, axis=0), jnp.maximum(soft, 0.0), 0.0)
 
-    finish0 = jnp.zeros(times.shape[0], dtype=times.dtype)
-    finish, _ = jax.lax.scan(step, finish0, d.topo)
+    finish = _level_scan(d, times, edge_delay, start)
     m = jnp.max(finish)
     return m + tau * jnp.log(jnp.sum(jnp.exp((finish - m) / tau)) + 1e-30)
 
 
 def hard_longest_path(d: PaddedDag, times: jnp.ndarray,
                       edge_delay: jnp.ndarray | None = None) -> jnp.ndarray:
-    def step(finish, j):
-        pf = finish[d.pred[j]]
-        if edge_delay is not None:
-            pf = pf + edge_delay[j]
-        pf = jnp.where(d.pred_mask[j], pf, 0.0)
-        finish = finish.at[j].set(jnp.max(pf, initial=0.0) + times[j])
-        return finish, ()
+    def start(pf, mask):
+        return jnp.max(jnp.where(mask, pf, 0.0), axis=0, initial=0.0)
 
-    finish0 = jnp.zeros(times.shape[0], dtype=times.dtype)
-    finish, _ = jax.lax.scan(step, finish0, d.topo)
-    return jnp.max(finish)
+    return jnp.max(_level_scan(d, times, edge_delay, start))
 
 
 @partial(jax.jit, static_argnames=("m", "k", "iters"))
@@ -336,7 +368,9 @@ def solve_hlp_jax(g: TaskGraph, m: int, k: int, iters: int = 400,
         return HLPSolution(x_frac=x, lp_value=float(val), alloc=alloc,
                            status="first-order")
     d = PaddedDag.from_graph(g)
-    with _obs.span("lp.device_solve", n=g.n, iters=int(iters)):
+    L, W = d.levels.shape
+    with _obs.span("lp.device_solve", n=g.n, iters=int(iters), levels=L,
+                   width=W):
         x, val = _solve(d, int(m), int(k), int(iters), int(seed))
         x = np.asarray(x, dtype=np.float64)   # blocks until the solve ends
     # λ(x) is exact for the returned iterate -> a *feasible* LP objective.
