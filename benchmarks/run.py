@@ -361,14 +361,12 @@ def _host_info() -> dict:
 
     import jax
 
-    from repro.sim import campaign_mesh, contention_kernel, shard_backend
+    from repro.sim import campaign_mesh
 
     return {
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "mesh_shape": {k: int(v) for k, v in campaign_mesh().shape.items()},
-        "shard_backend": shard_backend(),
-        "contention_kernel": contention_kernel(),
         "jax": jax.__version__,
         "python": _platform.python_version(),
     }
@@ -384,8 +382,7 @@ def write_bench_json(path: str, args, names: list[str],
 
     * ``schema``: the literal ``"repro.bench.v1"``.
     * ``run``: {seed, full, targets} — the harness invocation.
-    * ``host``: backend / device_count / mesh_shape / shard_backend /
-      contention_kernel / jax / python.
+    * ``host``: backend / device_count / mesh_shape / jax / python.
     * ``benches.<name>``: {wall_s, lines, ...extras} — every target gets
       its wall-clock and raw CSV lines; ``sim`` adds phase_seconds,
       compile counts, plans/evals, throughput_plans_per_sec(_per_device),

@@ -101,12 +101,11 @@ class _BatchScorer:
     """
 
     def __init__(self, g: TaskGraph, machine: Machine, batch: int, *,
-                 comm_tiebreak: bool, mesh=None):
+                 comm_tiebreak: bool):
         from repro.sim.batch import search_envelope
 
         self.g, self.machine, self.batch = g, machine, batch
         self.comm_tiebreak = comm_tiebreak
-        self.mesh = mesh
         self.pad_to = search_envelope(g, machine)
         self.cache: dict[bytes, float] = {}
         self.plans: dict[bytes, Plan] = {}
@@ -120,8 +119,7 @@ class _BatchScorer:
         pad = plans + [plans[0]] * (self.batch - len(plans))
         items = [(g, p) for p in pad]
         rows = [plan_times(g, p, g.proc)[None, :] for p in pad]
-        out = fixed_envelope_makespans(items, rows, self.pad_to,
-                                       mesh=self.mesh)
+        out = fixed_envelope_makespans(items, rows, self.pad_to)
         return [float(o[0]) for o in out[:len(plans)]]
 
     def score(self, genomes: list[Genome],
@@ -255,7 +253,7 @@ def _mutant(g, machine, gn: Genome, cfg: SearchConfig,
 
 
 def evolve_plan(g: TaskGraph, machine, config: SearchConfig | None = None,
-                *, seed: int = 0, mesh=None) -> SearchResult:
+                *, seed: int = 0) -> SearchResult:
     """Evolve a plan for ``(g, machine)``; see the module docstring.
 
     Bit-reproducible: all randomness flows from one
@@ -270,7 +268,7 @@ def evolve_plan(g: TaskGraph, machine, config: SearchConfig | None = None,
     seeds_p = seed_plans(g, machine, comm_aware=cfg.comm_aware,
                          adapters=cfg.seed_adapters)
     scorer = _BatchScorer(g, machine, cfg.pop_size + len(seeds_p),
-                          comm_tiebreak=cfg.comm_aware, mesh=mesh)
+                          comm_tiebreak=cfg.comm_aware)
     seed_genomes = [plan_to_genome(g, machine, p) for p in seeds_p.values()]
 
     # Generation 0: the seed genomes + random fill, scored alongside the

@@ -62,13 +62,10 @@ Beyond the paper's static pipeline it adds:
     and each bucket runs as one jitted vmapped scan (≤ 1 XLA compile per
     bucket), its plan axis sharded with ``shard_map`` over the explicit
     1-D ``campaign_mesh()`` when several devices are visible
-    (``set_campaign_mesh`` installs a custom mesh, ``REPRO_SHARD_BACKEND``
-    selects the legacy ``pmap`` path or disables sharding).  Contended
-    networks are priced by a jitted whole-bucket fluid fixpoint
-    (``contention_kernel``/``set_contention_kernel`` switch to the numpy
-    oracle).  Plan tensors carry the full (type, width) decision — the
-    width column rides along and realized times are curve-shrunk before
-    the scan.
+    (``set_campaign_mesh`` is the one place a mesh is chosen).  Contended
+    networks are priced by a jitted whole-bucket fluid fixpoint.  A
+    width-w decision enters the plan tensors as its w chain preds, and its
+    realized time is curve-shrunk before the scan.
 
 Entry points::
 
@@ -86,12 +83,11 @@ from repro.platform import Decision, Platform
 
 from .adapters import ADAPTERS, FrozenPlanScheduler, make_scheduler, plan_for
 from .batch import (campaign_mesh, reset_trace_counts, set_campaign_mesh,
-                    shard_backend, trace_count)
+                    trace_count)
 from .engine import (Machine, MachineState, NoiseModel, Plan, Scheduler,
                      SimResult, TraceEvent, plan_times, simulate)
 from .network import (NETWORKS, FixedLatencyNetwork, InstantNetwork,
-                      MaxMinFairNetwork, NetworkModel, contention_kernel,
-                      make_network, set_contention_kernel)
+                      MaxMinFairNetwork, NetworkModel, make_network)
 from .pipeline import (cached_allocate, clear_plan_cache, configure_xla_cache,
                        last_pipeline_stats, pipelined_sweep_makespans,
                        plan_cache_stats, plan_workers)
@@ -103,9 +99,8 @@ __all__ = [
     "Decision", "Platform", "Machine", "MachineState", "NoiseModel", "Plan",
     "Scheduler", "SimResult", "TraceEvent", "plan_times", "simulate",
     "NETWORKS", "NetworkModel", "InstantNetwork", "FixedLatencyNetwork",
-    "MaxMinFairNetwork", "contention_kernel", "make_network",
-    "set_contention_kernel",
-    "campaign_mesh", "set_campaign_mesh", "shard_backend",
+    "MaxMinFairNetwork", "make_network",
+    "campaign_mesh", "set_campaign_mesh",
     "reset_trace_counts", "trace_count",
     "cached_allocate", "clear_plan_cache", "configure_xla_cache",
     "last_pipeline_stats", "pipelined_sweep_makespans", "plan_cache_stats",

@@ -9,37 +9,33 @@ That structure is fixed per plan — the allocation decides once and for all
 which edges pay — so noise only perturbs the *node* weights and a whole
 batch of realizations evaluates as one ``vmap``ped ``lax.scan``.
 
-Two granularities:
-
-  * ``batch_makespans`` — one plan × (S,) noise realizations: the original
-    single-graph path, one jit per augmented-DAG shape.
-  * ``BatchedPlanDag`` + ``bucketed_makespans`` — *many different plans*
-    (different DAGs, different n, different pred fan-in P) evaluated
-    together: plans are grouped into buckets by the power-of-two envelope of
-    (n, P), padded to the per-bucket maxima, and each bucket runs as ONE
-    jitted vmap-over-plans of vmap-over-seeds scan.  A whole heterogeneous
-    campaign — the (scenario × scheduler × seed) grid of
-    ``benchmarks.campaign.sim_sweep`` — costs at most one XLA compile per
-    bucket (``trace_count()`` exposes the actual number for tests).  When
-    more than one device is visible the bucket's plan axis is sharded with
-    ``shard_map`` over the explicit 1-D ``campaign_mesh()``; the plan axis
-    is padded to a mesh-divisible count first (no divides-evenly
-    assumption) and sliced back.  ``REPRO_SHARD_BACKEND`` selects the
-    legacy ``pmap`` path or disables sharding for exact-parity checks.
+Every replay runs through one jitted program, ``_bucket_makespans``: a
+bucket of *many different plans* (different DAGs, different n, different
+pred fan-in P) padded to shared maxima and evaluated as ONE vmap-over-plans
+of vmap-over-seeds scan.  ``bucketed_makespans`` groups plans into buckets
+by the power-of-two envelope of (n, P); ``fixed_envelope_makespans`` pads
+one bucket to a caller-fixed envelope; ``batch_makespans`` is a bucket of
+one.  A whole heterogeneous campaign — the (scenario × scheduler × seed)
+grid of ``benchmarks.campaign.sim_sweep`` — costs at most one XLA compile
+per bucket (``trace_count()`` exposes the actual number for tests).  When
+the campaign mesh (``campaign_mesh()``, or the one ``set_campaign_mesh``
+installs) spans several devices, the bucket's plan axis is sharded with
+``shard_map``; the plan axis is padded to a mesh-divisible count first (no
+divides-evenly assumption) and sliced back.
 
 Contended networks (``maxmin_fair``) are priced at plan-DAG *build* time:
-by default a whole bucket of plans solves its replay/fluid fixpoint inside
-one jitted program (``contended_bucket_delays`` below, built on
-``network.fluid_finishes_jax``); ``set_contention_kernel("numpy")`` routes
-through the per-plan numpy oracle instead.  Either way contention enters
+a whole bucket of plans solves its replay/fluid fixpoint inside one jitted
+program (``contended_bucket_delays`` below, built on
+``network.fluid_finishes_jax``; the per-plan numpy
+``network.contended_plan_delays`` is its test oracle).  Contention enters
 ``pred_delay`` as numbers, never as new array shapes.
 
 Padding scheme: a plan with n tasks and max fan-in P lands in bucket
-``(next_pow2(n), next_pow2(P))`` and is padded to that bucket's maxima —
-phantom tasks have no predecessors and zero processing time, phantom order
-slots point at a phantom task, so they finish at time 0 and never move the
-max.  Padded entries of the times matrix are zero-filled by
-``_pad_times``.
+``(next_pow2(n + 1), next_pow2(P))`` (``_bucket_key``) and is padded to
+that bucket's maxima — phantom tasks have no predecessors and zero
+processing time, phantom order slots point at a phantom task, so they
+finish at time 0 and never move the max.  Padded entries of the times
+matrix are zero-filled by ``_pad_times``.
 
 Release times and busy-machine conditioning enter as per-task start
 *floors* (``PlanDag.floor``): a task starts no earlier than its floor, so a
@@ -54,9 +50,9 @@ property tests assert rtol <= 1e-5.
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 from collections import OrderedDict, defaultdict
+from collections.abc import Iterable
 from functools import partial
 
 import jax
@@ -72,7 +68,7 @@ from .engine import Machine, NoiseModel, Plan
 #: live *inside* the jitted function bodies, so each advances once per XLA
 #: trace (shape bucket), not once per call.  Tests assert <= 1 per bucket.
 #: The counts are the ``repro.obs`` counters ``sim.compile.<kind>``.
-TRACE_KINDS = ("bucket", "single", "contended")
+TRACE_KINDS = ("bucket", "contended")
 
 
 def _compile_counter(kind: str) -> str:
@@ -109,21 +105,6 @@ class PlanDag:
     floor: jnp.ndarray       # (n,)   per-task earliest-start floor (release
                              #        time / busy-machine conditioning); 0 =
                              #        the classic closed-campaign replay
-    width: jnp.ndarray       # (n,)   units each task occupies (moldable
-                             #        decisions).  The replay scan does not
-                             #        read it — a width-w task's occupancy is
-                             #        already encoded as its w chain preds and
-                             #        its curve-shrunk entry in ``times`` — but
-                             #        the plan tensor carries the full
-                             #        (type, width) decision so downstream
-                             #        introspection (and the width-aware
-                             #        samplers) never re-derive it.
-
-
-def _plan_delay_override(g: TaskGraph, plan: Plan, network):
-    """Per-edge delay vector a ``NetworkModel`` implies for this plan, or
-    ``None`` for the default fixed-latency charging."""
-    return _delay_overrides([(g, plan)], [network])[0]
 
 
 def _delay_overrides(items, networks) -> list:
@@ -131,13 +112,11 @@ def _delay_overrides(items, networks) -> list:
 
     Non-contended models reduce to closed-form delay arrays.  Contended
     models (``maxmin_fair``) price each plan through the fixed-start
-    max-min fluid fixpoint; by default all contended items of the list are
-    solved *together* by the jitted whole-bucket kernel
+    max-min fluid fixpoint; all contended items of the list are solved
+    *together* by the jitted whole-bucket kernel
     (:func:`contended_bucket_delays` — one compile per padded-shape
-    envelope), while ``set_contention_kernel("numpy")`` routes each through
-    the per-plan numpy oracle ``contended_plan_delays`` instead.  Either
-    way contention enters the plan DAG as delay *numbers*, never as new
-    array shapes.
+    envelope).  Contention enters the plan DAG as delay *numbers*, never
+    as new array shapes.
     """
     if networks is None:
         return [None] * len(items)
@@ -151,19 +130,10 @@ def _delay_overrides(items, networks) -> list:
         else:
             out[i] = net.plan_delays(g, plan.alloc)
     if contended:
-        from .network import contention_kernel
-        if contention_kernel() == "numpy":
-            from .engine import plan_times
-            from .network import contended_plan_delays
-            for i in contended:
-                g, plan = items[i]
-                out[i] = contended_plan_delays(
-                    g, plan, plan_times(g, plan, g.proc), networks[i])
-        else:
-            delays = contended_bucket_delays([items[i] for i in contended],
-                                             [networks[i] for i in contended])
-            for i, d in zip(contended, delays):
-                out[i] = d
+        delays = contended_bucket_delays([items[i] for i in contended],
+                                         [networks[i] for i in contended])
+        for i, d in zip(contended, delays):
+            out[i] = d
     return out
 
 
@@ -222,36 +192,9 @@ def _plan_arrays(g: TaskGraph, plan: Plan, delay_e: np.ndarray | None = None):
     return order, pred, delay, pred_eid
 
 
-def _plan_width(g: TaskGraph, plan: Plan) -> np.ndarray:
-    """(n,) width column of a plan's decisions (ones on rigid plans)."""
-    if plan.width is None:
-        return np.ones(g.n, dtype=np.int32)
-    return np.asarray(plan.width, dtype=np.int32)
-
-
-def build_plan_dag(g: TaskGraph, plan: Plan,
-                   floor: np.ndarray | None = None,
-                   network=None) -> PlanDag:
-    """Fuse DAG predecessors (with their transfer delays under the plan's
-    allocation) with each task's processor-sequence predecessors (one chain
-    pred per unit a width-w task occupies).
-
-    ``floor`` optionally gives each task an earliest-start time (release
-    times, or per-processor busy horizons when a rollout conditions on a
-    non-idle machine — see ``rollout_floors``).  ``network`` optionally
-    replaces the fixed-latency edge delays with a ``NetworkModel``'s
-    (contended models solve the max-min fluid fixpoint — see
-    ``_delay_overrides``)."""
-    order, pred, delay, _ = _plan_arrays(
-        g, plan, delay_e=_plan_delay_override(g, plan, network))
-    f = np.zeros(g.n) if floor is None else np.asarray(floor, dtype=np.float64)
-    return PlanDag(order=jnp.asarray(order), pred=jnp.asarray(pred),
-                   pred_mask=jnp.asarray(pred >= 0),
-                   pred_delay=jnp.asarray(delay), floor=jnp.asarray(f),
-                   width=jnp.asarray(_plan_width(g, plan)))
-
-
-def _one_makespan(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
+def _finish_times(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
+    """(n,) finish time of every task of one replay: the longest-path scan
+    over the augmented DAG's topological order."""
     def step(finish, j):
         pf = jnp.where(dag.pred_mask[j],
                        finish[dag.pred[j]] + dag.pred_delay[j], 0.0)
@@ -262,7 +205,11 @@ def _one_makespan(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
     # zeros_like, not zeros: under shard_map the carry must inherit the
     # varying mesh axes of ``times`` or the scan's carry types disagree
     finish, _ = jax.lax.scan(step, jnp.zeros_like(times), dag.order)
-    return jnp.max(finish)
+    return finish
+
+
+def _one_makespan(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
+    return jnp.max(_finish_times(dag, times))
 
 
 def rollout_floors(g: TaskGraph, plan: Plan, busy: list[np.ndarray],
@@ -284,18 +231,11 @@ def rollout_floors(g: TaskGraph, plan: Plan, busy: list[np.ndarray],
     return floor
 
 
-@jax.jit
-def _batch_makespans(dag: PlanDag, times: jnp.ndarray) -> jnp.ndarray:
-    _obs.bump("sim.compile.single")  # trace-time side effect: counts compiles
-    return jax.vmap(partial(_one_makespan, dag))(times)
-
-
 def batch_makespans(g: TaskGraph, plan: Plan, times: np.ndarray) -> np.ndarray:
-    """Makespan of the plan replayed under each row of ``times`` (S, n)."""
-    times = jnp.asarray(np.asarray(times, dtype=np.float64))
-    if times.ndim != 2 or times.shape[1] != g.n:
-        raise ValueError(f"times must be (S, n={g.n}), got {times.shape}")
-    return np.asarray(_batch_makespans(build_plan_dag(g, plan), times))
+    """Makespan of the plan replayed under each row of ``times`` (S, n): a
+    bucket of one, padded to exactly the plan's own (n, fan-in)."""
+    return bucketed_makespans([(g, plan)],
+                              [np.asarray(times, dtype=np.float64)])[0]
 
 
 #: Memo of noise multiplier grids, keyed by content: (kind, scale, n, the
@@ -387,8 +327,6 @@ class BatchedPlanDag:
     pred_mask: jnp.ndarray   # (B, n_pad, P_pad) bool
     pred_delay: jnp.ndarray  # (B, n_pad, P_pad) float
     floor: jnp.ndarray       # (B, n_pad) float — per-task start floors
-    width: jnp.ndarray       # (B, n_pad) int32 — decision widths (phantom
-                             #            tasks pad at width 1; see PlanDag)
 
     @property
     def batch(self) -> int:
@@ -430,22 +368,19 @@ class BatchedPlanDag:
         pred = np.full((B, n_pad, P_pad), -1, dtype=np.int32)
         delay = np.zeros((B, n_pad, P_pad), dtype=np.float64)
         floor = np.zeros((B, n_pad), dtype=np.float64)
-        width = np.ones((B, n_pad), dtype=np.int32)
         for b, (o, p, d, _) in enumerate(arrays):
             n, Pi = p.shape
             order[b, :n] = o
             order[b, n:] = n  # empty slice for the bucket's largest item
             pred[b, :n, :Pi] = p
             delay[b, :n, :Pi] = d
-            width[b, :n] = _plan_width(items[b][0], items[b][1])
             if floors is not None:
                 floor[b, :n] = floors[b]
         return BatchedPlanDag(order=jnp.asarray(order),
                               pred=jnp.asarray(pred),
                               pred_mask=jnp.asarray(pred >= 0),
                               pred_delay=jnp.asarray(delay),
-                              floor=jnp.asarray(floor),
-                              width=jnp.asarray(width))
+                              floor=jnp.asarray(floor))
 
 
 def _pad_times(times: np.ndarray, n_pad: int) -> np.ndarray:
@@ -458,18 +393,26 @@ def _pad_times(times: np.ndarray, n_pad: int) -> np.ndarray:
     return out
 
 
-def _bucket_key(g: TaskGraph, plan: Plan) -> tuple[int, int]:
-    """Power-of-two envelope of (n + 1 phantom slot, max augmented fan-in).
+def _pow2(x: int) -> int:
+    return 1 << max(0, int(np.ceil(np.log2(max(int(x), 1)))))
+
+
+def _envelope(g: TaskGraph, width: int) -> tuple[int, int]:
+    """Power-of-two envelope of (n + 1 phantom slot, max augmented fan-in)
+    for decisions at most ``width`` units wide.
 
     The augmented fan-in is bounded by the DAG fan-in plus one chain pred
-    per unit of the widest decision (1 on rigid plans); using the bound
-    (instead of the exact value) keeps the key cheap and stable.
+    per unit of the widest decision; using the bound (instead of the exact
+    value) keeps the envelope cheap and stable.
     """
-    n = g.n
     fan = int(np.diff(g.pred_ptr).max()) if g.n else 0
-    p = fan + (int(plan.width.max()) if plan.width is not None else 1)
-    return (1 << int(np.ceil(np.log2(max(n + 1, 2)))),
-            1 << int(np.ceil(np.log2(max(p, 1)))))
+    return _pow2(max(g.n + 1, 2)), _pow2(fan + width)
+
+
+def _bucket_key(g: TaskGraph, plan: Plan) -> tuple[int, int]:
+    """The envelope of the plan's own widest decision (1 on rigid plans)."""
+    return _envelope(g, int(plan.width.max()) if plan.width is not None
+                     else 1)
 
 
 def bucket_plans(items: list[tuple[TaskGraph, Plan]]
@@ -485,14 +428,13 @@ def bucket_plans(items: list[tuple[TaskGraph, Plan]]
 def _bucket_makespans(bd: BatchedPlanDag, times: jnp.ndarray) -> jnp.ndarray:
     _obs.bump("sim.compile.bucket")  # trace-time side effect: counts compiles
 
-    def per_item(order, pred, mask, delay, floor, width, t):
+    def per_item(order, pred, mask, delay, floor, t):
         return jax.vmap(partial(_one_makespan,
-                                PlanDag(order, pred, mask, delay, floor,
-                                        width)))(t)
+                                PlanDag(order, pred, mask, delay, floor)))(t)
 
     with jax.named_scope("replay_scan"):
         return jax.vmap(per_item)(bd.order, bd.pred, bd.pred_mask,
-                                  bd.pred_delay, bd.floor, bd.width, times)
+                                  bd.pred_delay, bd.floor, times)
 
 
 # -------------------------------------------------- contended bucket kernel
@@ -523,8 +465,9 @@ def _contended_durations(cb: ContendedBucket, num_links: int,
 
     The traceable mirror of :func:`repro.sim.network.contended_plan_delays`
     for a whole bucket at once: each round replays every plan's augmented
-    DAG under the current durations (the same ``lax.scan`` recurrence the
-    makespan path runs — transfer starts are the producers' finishes), then
+    DAG under the current durations (:func:`_finish_times`, the scan the
+    makespan path runs, at a zero floor — transfer starts are the
+    producers' finishes), then
     re-solves the fixed-start max-min fluid sub-problem with the masked
     event kernel :func:`repro.sim.network.fluid_finishes_jax`.  Plans whose
     durations stop moving (the oracle's ``allclose(rtol=1e-3, atol=1e-9)``
@@ -544,15 +487,8 @@ def _contended_durations(cb: ContendedBucket, num_links: int,
 
         def replay(dur):
             pd = jnp.where(tid >= 0, dur[jnp.maximum(tid, 0)], zero)
-
-            def step(finish, j):
-                pf = jnp.where(mask[j], finish[pred[j]] + pd[j], zero)
-                start = jnp.max(pf, initial=0.0)
-                return finish.at[j].set(start + times[j]), ()
-
-            finish, _ = jax.lax.scan(step, jnp.zeros(times.shape[0], fdt),
-                                     order)
-            return finish
+            return _finish_times(PlanDag(order, pred, mask, pd,
+                                         jnp.zeros_like(times)), times)
 
         def round_fn(_, carry):
             dur, done = carry
@@ -572,10 +508,6 @@ def _contended_durations(cb: ContendedBucket, num_links: int,
     return jax.vmap(per_plan)(cb.order, cb.pred, cb.pred_mask, cb.pred_tid,
                               cb.times, cb.src, cb.size, cb.up, cb.dn,
                               cb.t_mask, cb.capacity)
-
-
-def _pow2(x: int) -> int:
-    return 1 << max(0, int(np.ceil(np.log2(max(int(x), 1)))))
 
 
 def contended_bucket_delays(items: list, networks: list) -> list[np.ndarray]:
@@ -661,7 +593,6 @@ def contended_bucket_delays(items: list, networks: list) -> list[np.ndarray]:
 
 # ------------------------------------------------------- mesh execution layer
 _PLAN_AXIS = "plans"
-_SHARD_BACKENDS = ("shard_map", "pmap", "none")
 _MESH = None
 _SHARD_FNS: dict = {}
 
@@ -686,17 +617,6 @@ def set_campaign_mesh(mesh) -> None:
         raise ValueError(f"campaign mesh must have the single axis "
                          f"{_PLAN_AXIS!r}, got {mesh.axis_names}")
     _MESH = mesh
-
-
-def shard_backend() -> str:
-    """Which execution backend shards the plan axis: ``shard_map`` (the
-    mesh path, default), ``pmap`` (the legacy per-device path), or ``none``
-    (always single-program).  Env ``REPRO_SHARD_BACKEND`` selects."""
-    backend = os.environ.get("REPRO_SHARD_BACKEND", "shard_map")
-    if backend not in _SHARD_BACKENDS:
-        raise ValueError(f"unknown REPRO_SHARD_BACKEND={backend!r}; "
-                         f"have {_SHARD_BACKENDS}")
-    return backend
 
 
 def _pad_plan_axis(bd: BatchedPlanDag, times: jnp.ndarray, multiple: int):
@@ -730,60 +650,73 @@ def _shard_fn(mesh):
     return fn
 
 
-def _bucket_makespans_pmap(bd: BatchedPlanDag, times: jnp.ndarray,
-                           D: int) -> jnp.ndarray:
-    """Legacy pmap sharding, kept as a comparison backend.  The plan axis
-    is padded to a device-divisible count first (historically this path
-    silently required ``B % local_device_count() == 0`` whenever the padded
-    gather was skipped) and the result is sliced back."""
-    bd, times, B = _pad_plan_axis(bd, times, D)
-    shard = jax.tree_util.tree_map(
-        lambda a: a.reshape(D, -1, *a.shape[1:]), (bd, times))
-    out = jax.pmap(_bucket_makespans.__wrapped__)(*shard)
-    return out.reshape(-1, out.shape[-1])[:B]
-
-
-def _bucket_makespans_sharded(bd: BatchedPlanDag, times: jnp.ndarray,
-                              mesh=None) -> jnp.ndarray:
+def _bucket_makespans_sharded(bd: BatchedPlanDag,
+                              times: jnp.ndarray) -> jnp.ndarray:
     """Shard the plan axis of one bucket across the campaign mesh.
 
-    The default backend wraps the jitted vmapped scan in ``shard_map`` over
-    the explicit 1-D ``campaign_mesh()`` (``jax.sharding`` path); the plan
-    axis is padded to a mesh-divisible count (``_pad_plan_axis``) and
-    sliced back, with the round-trip shape asserted.  Because the program
-    is purely per-plan (a vmap), the sharded result equals the
-    single-device result bit-for-bit.  Single-device meshes (CPU CI) and
-    tiny buckets fall back to the single program unchanged.
+    The jitted vmapped scan runs under ``shard_map`` over the explicit 1-D
+    ``campaign_mesh()``; the plan axis is padded to a mesh-divisible count
+    (``_pad_plan_axis``) and sliced back, with the round-trip shape
+    asserted.  Because the program is purely per-plan (a vmap), the
+    sharded result equals the single-device result bit-for-bit.
+    Single-device meshes (CPU CI) and one-plan buckets run the single
+    program unchanged.
     """
-    backend = shard_backend()
+    mesh = campaign_mesh()
+    D = int(mesh.devices.size)
     B, S = times.shape[0], times.shape[1]
-    if backend == "pmap":
-        D = jax.local_device_count()
-        if D <= 1 or B < 2:
-            return _bucket_makespans(bd, times)
-        out = _bucket_makespans_pmap(bd, times, D)
-    elif backend == "shard_map":
-        mesh = campaign_mesh() if mesh is None else mesh
-        D = int(mesh.devices.size)
-        if D <= 1 or B < 2:
-            return _bucket_makespans(bd, times)
-        bdp, tp, _ = _pad_plan_axis(bd, times, D)
-        with _obs.span("sim.shard.dispatch", backend="shard_map",
-                       devices=D, plans=B):
-            out = _shard_fn(mesh)(bdp, tp)[:B]
-    else:   # "none": always the single program
+    if D <= 1 or B < 2:
         return _bucket_makespans(bd, times)
+    bdp, tp, _ = _pad_plan_axis(bd, times, D)
+    with _obs.span("sim.shard.dispatch", backend="shard_map", devices=D,
+                   plans=B):
+        out = _shard_fn(mesh)(bdp, tp)[:B]
     assert out.shape == (B, S), \
         f"plan-axis round trip broke: {out.shape} != {(B, S)}"
     return out
+
+
+def _check_grids(items: list[tuple[TaskGraph, Plan]],
+                 times: list[np.ndarray]) -> None:
+    """Each item's times matrix is (S, n_i), with one S for all items."""
+    if len(items) != len(times):
+        raise ValueError("items and times must align")
+    S = {t.shape[0] for t in times}
+    if len(S) > 1:
+        raise ValueError(f"all items must share one seed grid, got S={sorted(S)}")
+    for (g, _), t in zip(items, times):
+        if t.ndim != 2 or t.shape[1] != g.n:
+            raise ValueError(f"times must be (S, n={g.n}), got {t.shape}")
+
+
+def _assemble_bucket(items: list[tuple[TaskGraph, Plan]],
+                     times: Iterable[np.ndarray],
+                     pad_to: tuple[int, int] | None = None,
+                     floors: list[np.ndarray] | None = None,
+                     networks: list | None = None
+                     ) -> tuple[BatchedPlanDag, np.ndarray]:
+    """One bucket's device inputs: the stacked plan-DAGs
+    (:meth:`BatchedPlanDag.from_plans`) and the (B, S, n_pad) stack of the
+    items' zero-padded time grids.  ``times`` is read once, after the
+    plan-DAGs, one grid at a time: a generator that hands over each grid
+    lets it be freed as soon as its padded copy exists.  With ``pad_to``,
+    every item must fit that envelope — a larger plan would silently grow
+    the compiled shape, so it raises ``ValueError`` instead."""
+    bd = BatchedPlanDag.from_plans(items, floors=floors, pad_to=pad_to,
+                                   networks=networks)
+    if pad_to is not None and (bd.n_pad, bd.pred.shape[2]) != tuple(pad_to):
+        raise ValueError(f"item exceeds the envelope {tuple(pad_to)}: bucket "
+                         f"padded to {(bd.n_pad, bd.pred.shape[2])}")
+    tt = np.stack([_pad_times(np.asarray(t, dtype=np.float64), bd.n_pad)
+                   for t in times])
+    return bd, tt
 
 
 def bucketed_makespans(items: list[tuple[TaskGraph, Plan]],
                        times: list[np.ndarray],
                        floors: list[np.ndarray] | None = None,
                        envelope: bool = False,
-                       networks: list | None = None,
-                       mesh=None) -> list[np.ndarray]:
+                       networks: list | None = None) -> list[np.ndarray]:
     """Replay many different plans under per-plan times matrices.
 
     Args:
@@ -800,46 +733,31 @@ def bucketed_makespans(items: list[tuple[TaskGraph, Plan]],
              entries — edge delays are replaced at plan-DAG build time
              (contended models via the jitted whole-bucket fluid fixpoint),
              so the bucketed path stays at <= 1 XLA compile per bucket.
-      mesh: optional explicit device mesh to shard each bucket's plan axis
-             over (defaults to ``campaign_mesh()``; single-device meshes
-             run the plain single program).
 
     Returns a list of (S,) makespan arrays, one per item, in input order.
     Cost: one jitted vmapped scan per *bucket* (power-of-two envelope of
     (n, fan-in)), not per item — ``trace_count('bucket')`` measures it.
     """
-    if len(items) != len(times):
-        raise ValueError("items and times must align")
+    _check_grids(items, times)
     if floors is not None and len(floors) != len(items):
         raise ValueError("floors and items must align")
     if networks is not None and len(networks) != len(items):
         raise ValueError("networks and items must align")
-    if not items:
-        return []
-    S = {t.shape[0] for t in times}
-    if len(S) != 1:
-        raise ValueError(f"all items must share one seed grid, got S={sorted(S)}")
-    for (g, _), t in zip(items, times):
-        if t.ndim != 2 or t.shape[1] != g.n:
-            raise ValueError(f"times must be (S, n={g.n}), got {t.shape}")
 
     out: list[np.ndarray | None] = [None] * len(items)
     for key, idxs in bucket_plans(items).items():
         with _obs.span("sim.bucket.build", bucket=f"{key[0]}x{key[1]}",
                        plans=len(idxs)):
-            bd = BatchedPlanDag.from_plans(
-                [items[i] for i in idxs],
+            bd, tt = _assemble_bucket(
+                [items[i] for i in idxs], [times[i] for i in idxs],
+                pad_to=key if envelope else None,
                 floors=([floors[i] for i in idxs]
                         if floors is not None else None),
-                pad_to=key if envelope else None,
                 networks=([networks[i] for i in idxs]
                           if networks is not None else None))
-            tt = np.stack([_pad_times(np.asarray(times[i], dtype=np.float64),
-                                      bd.n_pad) for i in idxs])
         with _obs.span("sim.bucket.execute", bucket=f"{key[0]}x{key[1]}",
                        plans=len(idxs)):
-            ms = np.asarray(_bucket_makespans_sharded(bd, jnp.asarray(tt),
-                                                      mesh=mesh))
+            ms = np.asarray(_bucket_makespans_sharded(bd, jnp.asarray(tt)))
         for row, i in enumerate(idxs):
             out[i] = ms[row]
     return out  # type: ignore[return-value]
@@ -848,8 +766,8 @@ def bucketed_makespans(items: list[tuple[TaskGraph, Plan]],
 def fixed_envelope_makespans(items: list[tuple[TaskGraph, Plan]],
                              times: list[np.ndarray],
                              pad_to: tuple[int, int],
-                             floors: list[np.ndarray] | None = None,
-                             mesh=None) -> list[np.ndarray]:
+                             floors: list[np.ndarray] | None = None
+                             ) -> list[np.ndarray]:
     """Replay many plans as ONE bucket padded to a caller-fixed envelope.
 
     :func:`bucketed_makespans` keys each plan by its own power-of-two
@@ -866,30 +784,16 @@ def fixed_envelope_makespans(items: list[tuple[TaskGraph, Plan]],
 
     Returns a list of (S,) makespan arrays, one per item, in input order.
     """
-    if len(items) != len(times):
-        raise ValueError("items and times must align")
+    _check_grids(items, times)
     if not items:
         return []
-    S = {t.shape[0] for t in times}
-    if len(S) != 1:
-        raise ValueError(f"all items must share one seed grid, got S={sorted(S)}")
-    for (g, _), t in zip(items, times):
-        if t.ndim != 2 or t.shape[1] != g.n:
-            raise ValueError(f"times must be (S, n={g.n}), got {t.shape}")
     with _obs.span("sim.bucket.build", bucket=f"{pad_to[0]}x{pad_to[1]}",
                    plans=len(items)):
-        bd = BatchedPlanDag.from_plans(items, floors=floors, pad_to=pad_to)
-        if (bd.n_pad, bd.pred.shape[2]) != tuple(pad_to):
-            raise ValueError(
-                f"item exceeds the fixed envelope {tuple(pad_to)}: bucket "
-                f"padded to {(bd.n_pad, bd.pred.shape[2])}")
-        tt = np.stack([_pad_times(np.asarray(t, dtype=np.float64), bd.n_pad)
-                       for t in times])
+        bd, tt = _assemble_bucket(items, times, pad_to=pad_to, floors=floors)
     with _obs.span("sim.bucket.execute", bucket=f"{pad_to[0]}x{pad_to[1]}",
                    plans=len(items)):
-        ms = np.asarray(_bucket_makespans_sharded(bd, jnp.asarray(tt),
-                                                  mesh=mesh))
-    return [ms[i] for i in range(len(items))]
+        ms = np.asarray(_bucket_makespans_sharded(bd, jnp.asarray(tt)))
+    return list(ms)
 
 
 def search_envelope(g: TaskGraph, machine) -> tuple[int, int]:
@@ -902,15 +806,12 @@ def search_envelope(g: TaskGraph, machine) -> tuple[int, int]:
     from repro.platform import as_platform
 
     counts = as_platform(machine, warn=False).to_counts()
-    n = g.n
-    fan = int(np.diff(g.pred_ptr).max()) if g.n else 0
-    wcap = max(1, min(int(g.max_width), max(counts)))
-    return (_pow2(n + 1), _pow2(fan + wcap))
+    return _envelope(g, max(1, min(int(g.max_width), max(counts))))
 
 
 def sweep_suite_makespans(entries, *, noise: NoiseModel, seeds,
                           floor_fn=None, envelope: bool = False,
-                          network=None, mesh=None, workers: int = 1,
+                          network=None, workers: int = 1,
                           cache: bool = False) -> list[np.ndarray]:
     """One-jit-per-bucket campaign sweep over heterogeneous (g, machine,
     scheduler) entries: allocate each plan once, sample its noise grid with
@@ -922,8 +823,7 @@ def sweep_suite_makespans(entries, *, noise: NoiseModel, seeds,
     pads to the full bucket envelope so repeated small sweeps — the
     simulation-in-the-loop rollout pattern of ``repro.streams.policy`` —
     stay at one XLA compile per shape bucket across calls.  ``network``
-    applies one ``NetworkModel`` to every entry's replay; ``mesh``
-    overrides the campaign mesh the plan axis shards over.
+    applies one ``NetworkModel`` to every entry's replay.
 
     ``workers`` and ``cache`` route through the *pipelined* executor
     (:func:`repro.sim.pipeline.pipelined_sweep_makespans`): plan
@@ -941,7 +841,7 @@ def sweep_suite_makespans(entries, *, noise: NoiseModel, seeds,
         from .pipeline import pipelined_sweep_makespans
         return pipelined_sweep_makespans(
             entries, noise=noise, seeds=seeds, floor_fn=floor_fn,
-            network=network, workers=workers, cache=cache, mesh=mesh)
+            network=network, workers=workers, cache=cache)
     items, rows, floors = [], [], []
     for g, machine, scheduler in entries:
         plan = scheduler.allocate(g, machine)
@@ -956,5 +856,4 @@ def sweep_suite_makespans(entries, *, noise: NoiseModel, seeds,
                               floors=floors if floor_fn is not None else None,
                               envelope=envelope,
                               networks=([network] * len(items)
-                                        if network is not None else None),
-                              mesh=mesh)
+                                        if network is not None else None))

@@ -41,18 +41,16 @@ Three consumers of a model:
     when a new one starts (first-come-frozen fluid approximation), which
     keeps decisions causal at the cost of slightly optimistic sharing;
   * the bucketed JAX path prices each plan through the same fixed-start
-    max-min fluid fixpoint, evaluated either by the plain-numpy reference
-    (:func:`contended_plan_delays`, the oracle) or — the default — by a
-    jitted, vmappable fixed-iteration kernel (:func:`fluid_finishes_jax`
-    plus the whole-bucket fixpoint in ``repro.sim.batch``) so a bucket of
-    plans solves its contention inside one compiled program instead of a
-    per-plan numpy loop.  :func:`set_contention_kernel` switches the two
-    (env ``REPRO_CONTENTION_KERNEL``); they agree to rtol 1e-6.
+    max-min fluid fixpoint, evaluated by a jitted, vmappable
+    fixed-iteration kernel (:func:`fluid_finishes_jax` plus the
+    whole-bucket fixpoint in ``repro.sim.batch``) so a bucket of plans
+    solves its contention inside one compiled program.  The plain-numpy
+    :func:`contended_plan_delays` / :func:`_fluid_finishes` pair is its
+    reference oracle; the tests hold the two to rtol 1e-6.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import numpy as np
 
@@ -450,25 +448,6 @@ def contended_plan_delays(g: TaskGraph, plan, times: np.ndarray,
 #: kernel, so the two implementations run the same iteration schedule.
 CONTENTION_ITERS = 4
 
-_CONTENTION_KERNELS = ("jax", "numpy")
-_contention_kernel = os.environ.get("REPRO_CONTENTION_KERNEL", "jax")
-
-
-def contention_kernel() -> str:
-    """Which implementation prices contention on the bucketed batch path:
-    ``"jax"`` (the jitted whole-bucket fixpoint, default) or ``"numpy"``
-    (the per-plan reference oracle).  Env ``REPRO_CONTENTION_KERNEL``
-    overrides the default at import time."""
-    return _contention_kernel
-
-
-def set_contention_kernel(name: str) -> None:
-    global _contention_kernel
-    if name not in _CONTENTION_KERNELS:
-        raise ValueError(f"unknown contention kernel {name!r}; "
-                         f"have {_CONTENTION_KERNELS}")
-    _contention_kernel = name
-
 
 def _maxmin_rates_jax(active, up, dn, capacity, num_links: int):
     """(T,) max-min fair rates by *masked* progressive filling (traceable).
@@ -564,7 +543,6 @@ def fluid_finishes_jax(starts, sizes, up, dn, mask, capacity,
 __all__ = [
     "CONTENTION_ITERS", "NETWORKS", "NetworkModel", "InstantNetwork",
     "FixedLatencyNetwork", "MaxMinFairNetwork", "PlanTransfers",
-    "TransferTracker", "contended_plan_delays", "contention_kernel",
-    "fluid_finishes_jax", "make_network", "maxmin_rates", "plan_transfers",
-    "set_contention_kernel",
+    "TransferTracker", "contended_plan_delays", "fluid_finishes_jax",
+    "make_network", "maxmin_rates", "plan_transfers",
 ]

@@ -62,8 +62,8 @@ import jax.numpy as jnp
 
 from repro.core.dag import TaskGraph
 from repro.obs import registry as _obs
-from repro.sim.batch import (BatchedPlanDag, _bucket_makespans_sharded,
-                             _pad_times, sample_actual_batch, search_envelope)
+from repro.sim.batch import (_assemble_bucket, _bucket_makespans_sharded,
+                             sample_actual_batch, search_envelope)
 from repro.sim.engine import NoiseModel, Plan
 
 __all__ = [
@@ -495,8 +495,8 @@ def last_pipeline_stats() -> PipelineStats:
 def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
                               sample_fn=None, floor_fn=None,
                               network=None, networks=None,
-                              workers: int | None = None, cache: bool = True,
-                              mesh=None) -> list[np.ndarray]:
+                              workers: int | None = None, cache: bool = True
+                              ) -> list[np.ndarray]:
     """The pipelined drop-in for :func:`repro.sim.batch.sweep_suite_makespans`:
     same ``(g, machine, scheduler)`` entries, same ``(S,)``-array-per-entry
     result, bit-identical values — built by the parallel/cached/overlapped
@@ -576,26 +576,19 @@ def pipelined_sweep_makespans(entries, *, noise: NoiseModel = None, seeds=(),
                 with _obs.timer("sim.pipeline.assemble", bucket=bucket,
                                 plans=len(idxs)) as t:
                     items = [(entries[j][0], plans[j]) for j in idxs]
-                    bd = BatchedPlanDag.from_plans(
-                        items, pad_to=key,
+                    bd, tt = _assemble_bucket(
+                        items, (grids.pop(j) for j in idxs), pad_to=key,
                         floors=([np.asarray(floor_fn(g_j, p_j),
                                             dtype=np.float64)
                                  for g_j, p_j in items]
                                 if floor_fn is not None else None),
                         networks=([networks[j] for j in idxs]
                                   if networks is not None else None))
-                    if (bd.n_pad, bd.pred.shape[2]) != key:
-                        raise AssertionError(
-                            f"plan escaped its envelope {key}: bucket "
-                            f"padded to {(bd.n_pad, bd.pred.shape[2])}")
-                    tt = np.stack([_pad_times(grids.pop(j), bd.n_pad)
-                                   for j in idxs])
                 stats.assemble_s += t.dur
                 with _obs.timer("sim.pipeline.launch", bucket=bucket) as t:
                     # async dispatch: the device starts here, the host
                     # moves on
-                    ms = _bucket_makespans_sharded(bd, jnp.asarray(tt),
-                                                   mesh=mesh)
+                    ms = _bucket_makespans_sharded(bd, jnp.asarray(tt))
                 stats.launch_s += t.dur
                 in_flight.append((key, idxs, ms))
                 if first_dispatch is None:

@@ -33,12 +33,12 @@ API = {
         "NETWORKS", "NetworkModel", "NoiseModel", "Plan", "Platform",
         "SCENARIO_FAMILIES", "Scenario", "Scheduler", "SimResult",
         "TraceEvent", "cached_allocate", "campaign_mesh",
-        "clear_plan_cache", "configure_xla_cache", "contention_kernel",
+        "clear_plan_cache", "configure_xla_cache",
         "default_suite", "from_estee", "last_pipeline_stats", "make_network",
         "make_scenario", "make_scheduler", "moldable_suite",
         "pipelined_sweep_makespans", "plan_cache_stats", "plan_for",
         "plan_times", "plan_workers", "reset_trace_counts",
-        "set_campaign_mesh", "set_contention_kernel", "shard_backend",
+        "set_campaign_mesh",
         "simulate", "to_estee", "trace_count",
     ],
     "repro.obs": [

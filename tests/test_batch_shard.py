@@ -1,10 +1,10 @@
-"""Mesh-sharded bucket execution: padding round trips and backend parity.
+"""Mesh-sharded bucket execution: padding round trips and mesh parity.
 
 The plan axis of each bucket shards over the explicit 1-D campaign mesh
-(``shard_map``) or the legacy ``pmap`` path; both pad the plan axis to a
-shard-divisible count first (``_pad_plan_axis``) so no divides-evenly
-assumption survives — the regression tests pin a *prime* plan count.
-Multi-device behavior is exercised in a subprocess with
+(``shard_map``), padded to a shard-divisible count first
+(``_pad_plan_axis``) so no divides-evenly assumption survives — the
+regression tests pin a *prime* plan count.  Multi-device behavior is
+exercised in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count`` (the parent process
 pins the single-device CPU topology at jax import).
 """
@@ -16,7 +16,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.sim import make_scheduler, set_campaign_mesh, shard_backend
+from repro.sim import make_scheduler, set_campaign_mesh
 from repro.sim.batch import (BatchedPlanDag, _pad_plan_axis,
                              sample_actual_batch)
 from repro.sim.engine import NoiseModel
@@ -55,12 +55,6 @@ def test_pad_plan_axis_round_trip(B, multiple):
                                           (want - B, 1)))
 
 
-def test_shard_backend_env_validation(monkeypatch):
-    monkeypatch.setenv("REPRO_SHARD_BACKEND", "mpi")
-    with pytest.raises(ValueError, match="unknown REPRO_SHARD_BACKEND"):
-        shard_backend()
-
-
 def test_set_campaign_mesh_validates_axis_name():
     import jax
     from jax.sharding import Mesh
@@ -70,13 +64,15 @@ def test_set_campaign_mesh_validates_axis_name():
 
 
 _SUBPROCESS_PARITY = textwrap.dedent("""
-    import os
     import numpy as np
     import jax
+    from jax.sharding import Mesh
     assert jax.local_device_count() == 4, jax.local_device_count()
 
-    from repro.sim import make_scheduler
-    from repro.sim.batch import bucketed_makespans, sample_actual_batch
+    from repro.sim import make_scheduler, set_campaign_mesh
+    from repro.sim.batch import (_bucket_key, bucketed_makespans,
+                                 campaign_mesh, fixed_envelope_makespans,
+                                 sample_actual_batch)
     from repro.sim.engine import NoiseModel
     from repro.sim.scenarios import default_suite
 
@@ -88,28 +84,34 @@ _SUBPROCESS_PARITY = textwrap.dedent("""
             items.append((sc.graph, plan))
             times.append(sample_actual_batch(sc.graph, plan, noise, [0, 1, 2]))
     items, times = items[:7], times[:7]   # prime plan count: 7 % 4 != 0
+    pad_to = tuple(np.max([_bucket_key(g, p) for g, p in items], axis=0))
 
-    def run(backend):
-        os.environ["REPRO_SHARD_BACKEND"] = backend
-        return bucketed_makespans(items, times)
+    def run():
+        # per-key buckets, then all seven plans in one envelope bucket
+        return (bucketed_makespans(items, times)
+                + fixed_envelope_makespans(items, times, pad_to))
 
-    shard, pmap, single = run("shard_map"), run("pmap"), run("none")
-    for a, b in zip(shard, pmap):
-        assert np.array_equal(a, b), "shard_map != pmap"
+    assert campaign_mesh().devices.size == 4
+    shard = run()
+    set_campaign_mesh(Mesh(np.asarray(jax.devices()[:1]), ("plans",)))
+    try:
+        single = run()
+    finally:
+        set_campaign_mesh(None)
     for a, b in zip(shard, single):
-        assert np.array_equal(a, b), "shard_map != single-device"
+        assert np.array_equal(a, b), "shard_map != one-device mesh"
     print("PARITY_OK")
 """)
 
 
 def test_shard_map_reproduces_pmap_across_four_devices():
-    """shard_map == pmap == single-device, bit-for-bit, at a prime plan
-    count on a forced 4-device CPU topology (subprocess: the device count
-    is fixed at jax import)."""
+    """The shard_map program over a four-device campaign mesh equals the
+    one-device mesh (set through ``set_campaign_mesh``) bit-for-bit, at a
+    prime plan count on a forced 4-device CPU topology (subprocess: the
+    device count is fixed at jax import)."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH="src")
-    env.pop("REPRO_SHARD_BACKEND", None)
     proc = subprocess.run([sys.executable, "-c", _SUBPROCESS_PARITY],
                           capture_output=True, text=True, env=env,
                           cwd=os.path.join(os.path.dirname(__file__), ".."),

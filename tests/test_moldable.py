@@ -269,17 +269,6 @@ def test_moldable_batch_path_matches_engine():
         np.testing.assert_allclose(ms, ref, rtol=1e-5)
 
 
-def test_width_column_rides_the_plan_tensors():
-    sc = moldable_suite(seed=2, num=1)[0]
-    plan = make_scheduler("mhlp_ols").allocate(sc.graph, sc.machine)
-    dag = batch.build_plan_dag(sc.graph, plan)
-    np.testing.assert_array_equal(np.asarray(dag.width),
-                                  np.asarray(plan.width))
-    bd = batch.BatchedPlanDag.from_plans([(sc.graph, plan)])
-    np.testing.assert_array_equal(np.asarray(bd.width[0, :sc.graph.n]),
-                                  np.asarray(plan.width))
-
-
 # --------------------------------------------------------- the campaign win
 def test_width_aware_mhlp_beats_width1_restriction_bucketed():
     """The acceptance claim: on the checked-in ``moldable_cholesky`` family

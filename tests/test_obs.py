@@ -80,7 +80,7 @@ def test_gauges_and_snapshot():
 
 # ---------------------------------------------------------- trace-count shim
 def test_trace_count_rejects_unknown_kind_listing_valid_ones():
-    with pytest.raises(ValueError, match="bucket, single, contended"):
+    with pytest.raises(ValueError, match="bucket, contended"):
         trace_count("nope")
 
 
@@ -89,7 +89,6 @@ def test_reset_trace_counts_zeroes_all_kinds():
     obs.bump("sim.compile.contended", 1)
     reset_trace_counts()
     assert trace_count("bucket") == 0
-    assert trace_count("single") == 0
     assert trace_count("contended") == 0
 
 

@@ -84,8 +84,7 @@ def test_bucket_makespans_compiles_at_potrf_nb20(one_chip):
         pred=_spec(one_chip, (B, n_pad, p_pad), jnp.int32),
         pred_mask=_spec(one_chip, (B, n_pad, p_pad), jnp.bool_),
         pred_delay=_spec(one_chip, (B, n_pad, p_pad), f),
-        floor=_spec(one_chip, (B, n_pad), f),
-        width=_spec(one_chip, (B, n_pad), jnp.int32))
+        floor=_spec(one_chip, (B, n_pad), f))
     times = _spec(one_chip, (B, S, n_pad), f)
     compiled = _bucket_makespans.lower(bd, times).compile()
     _fits_one_chip(compiled)
